@@ -12,9 +12,10 @@ gate's producer and its re-checker:
     PYTHONPATH=src python scripts/train_rl_baseline.py --scale 0.1
 
 Training runs the fused on-device trainer (repro.core.rl.batched_train)
-with fixed seeds over a scenario × load-scale randomized episode stream;
-the greedy policy is then evaluated on its 15-min training cadence against
-the forecast controller over every registered scenario family (same seeds
+at the configuration in repro.core.rl.baseline, with fixed seeds over a
+scenario × load-scale randomized episode stream; the greedy policy is
+then evaluated on its 15-min training cadence against the forecast
+controller over every registered scenario family (same seeds
 → identical job streams per family) at the standard ``--scale 0.1``
 sizing, and the summary lands in ``benchmarks/baselines/rl_batched.json``
 next to the params (``rl_dqn_params.npz``).  The DQN side evaluates
@@ -44,21 +45,16 @@ REPO_ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 PARAMS_OUT = os.path.join(REPO_ROOT, "benchmarks", "baselines", "rl_dqn_params.npz")
 BASELINE_OUT = os.path.join(REPO_ROOT, "benchmarks", "baselines", "rl_batched.json")
 
-#: evaluation cadence = the batched trainer's decision cadence
-DECISION_INTERVAL_MIN = 15.0
-
-#: scenario families the trained policy is raced on (fixed order, as in
-#: the sweep grids); training draws episodes from the same families so
-#: the policy sees every arrival shape it is evaluated under
-TRAIN_SCENARIOS = (
-    "paper-diurnal",
-    "bursty-mmpp",
-    "heavy-tail-lognormal",
-    "heavy-tail-pareto",
+from repro.core.rl.baseline import (
+    DECISION_INTERVAL_MIN,
+    LOAD_SCALE_RANGE,
+    TRAIN_EPISODES,
+    TRAIN_SCENARIOS,
+    TRAIN_SEED,
+    dqn_config,
+    train,
 )
 
-TRAIN_SEED = 7
-TRAIN_EPISODES = 2048
 EVAL_SEED = 90_000
 
 
@@ -76,42 +72,6 @@ def _git_sha() -> str:
         return "unknown"
 
 
-def _dqn_config():
-    from repro.core.rl.dqn import DQNConfig
-    from repro.core.rl.env import FEATURE_DIM
-
-    return DQNConfig(
-        state_dim=FEATURE_DIM,
-        n_step=8,
-        lr=3e-4,
-        target_sync_every=2000,
-        min_buffer=2000,
-        eps_decay_steps=100_000,
-        seed=TRAIN_SEED,
-    )
-
-
-def train(episodes: int = TRAIN_EPISODES, verbose: bool = True):
-    """Fixed-seed batched training over the scenario × load-scale mix."""
-    from repro.core.rl.batched_train import BatchedTrainConfig, train_dqn_batched
-
-    tcfg = BatchedTrainConfig(
-        batch=64,
-        scenarios=TRAIN_SCENARIOS,
-        load_scale_range=(0.8, 1.2),
-        decision_interval_min=DECISION_INTERVAL_MIN,
-        horizon_decisions=104,
-    )
-    learner, stats = train_dqn_batched(
-        num_episodes=episodes,
-        dqn_config=_dqn_config(),
-        train_config=tcfg,
-        seed=TRAIN_SEED,
-        verbose=verbose,
-    )
-    return learner, stats
-
-
 def evaluate(params_path: str, scale: float = 0.1, workers: int = 0) -> list:
     """Race the saved policy against the forecast controller per family.
 
@@ -124,7 +84,7 @@ def evaluate(params_path: str, scale: float = 0.1, workers: int = 0) -> list:
     from repro.core.rl import DQNLearner, evaluate_policy, greedy_policy
     from repro.sweep.grids import SCENARIO_ORDER, _iters
 
-    learner = DQNLearner(_dqn_config())
+    learner = DQNLearner(dqn_config())
     learner.load(params_path)
     iters = _iters(40, scale, floor=4)
     rows = []
@@ -183,7 +143,7 @@ def _params_probe(params_path: str, seed: int = 123, n: int = 16) -> dict:
     from repro.core.rl import DQNLearner
     from repro.core.rl.env import FEATURE_DIM
 
-    learner = DQNLearner(_dqn_config())
+    learner = DQNLearner(dqn_config())
     learner.load(params_path)
     rng = np.random.default_rng(seed)
     obs = rng.uniform(0.0, 1.0, size=(n, FEATURE_DIM))
@@ -236,7 +196,7 @@ def main(argv=None) -> int:
             "episodes": args.episodes,
             "seed": TRAIN_SEED,
             "scenarios": list(TRAIN_SCENARIOS),
-            "load_scale_range": [0.8, 1.2],
+            "load_scale_range": list(LOAD_SCALE_RANGE),
             "decision_interval_min": DECISION_INTERVAL_MIN,
         },
         "eval_seed": EVAL_SEED,
@@ -266,4 +226,7 @@ def main(argv=None) -> int:
 
 
 if __name__ == "__main__":
+    from repro.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     sys.exit(main())

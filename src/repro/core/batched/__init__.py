@@ -15,11 +15,13 @@ Public surface:
 * :func:`compile_policy` / :class:`BatchedPolicy` — oracle policies
   compiled to per-rollout target arrays (static/nomig/daynight);
 * :func:`simulate_batch` — run a batch to completion (jax imported here);
-* :class:`BatchedRepartitionEnv` — the vectorized RL environment.
+* :class:`BatchedRepartitionEnv` — the vectorized RL environment;
+* :func:`agreement_failures` — the §4 agreement contract with the oracle.
 
 Importing the package is jax-free; jax loads on the first simulated step.
 """
 
+from repro.core.batched.agreement import agreement_failures
 from repro.core.batched.backend import (
     DEFAULT_CHUNK_STEPS,
     DEFAULT_DT_MIN,
@@ -47,6 +49,7 @@ __all__ = [
     "DeviceTables",
     "RolloutState",
     "UnsupportedPolicyError",
+    "agreement_failures",
     "build_tables",
     "compile_policy",
     "held_policy",

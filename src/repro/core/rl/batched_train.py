@@ -30,9 +30,10 @@ pads them to one global shape so every round reuses one compiled program,
 and finally installs the trained parameters into a plain
 :class:`DQNLearner` — downstream evaluation/persistence is unchanged.
 
-Rollout-batched arrays are sharded across available devices with
-``jax.sharding`` (:func:`shard_rollouts`); on the single-device CPU cell
-this degrades to a no-op placement.
+Rollout-batched arrays are sharded across the devices with
+``jax.sharding`` (:func:`shard_rollouts`); given one device, they are
+pinned to it.  tests/test_batched_train.py checks on four virtual CPU
+devices that a sharded round equals the one-device round.
 """
 
 from __future__ import annotations
@@ -205,23 +206,28 @@ def device_observations(
 def shard_rollouts(tree, devices=None):
     """Place rollout-batched arrays across devices on a 1-D ``rollout`` mesh.
 
-    Leaves whose leading axis equals the batch size get a
-    ``NamedSharding(P("rollout"))``; everything else is left replicated.
-    Degrades to the identity when only one device is visible or the batch
-    does not divide the device count, so the single-CPU cell and tests are
-    unaffected (the multi-device path is exercised via the subprocess
-    pattern of tests/helpers/sharded_smoke.py).
+    Leaves whose leading axis equals the batch size (the first leaf's) get a
+    ``NamedSharding(P("rollout"))``; every other leaf is left as it is.  One
+    device holds the whole batch, so ``devices=[d]`` pins the rollouts to
+    ``d``; with ``devices`` left out and one device in all, the tree is
+    returned as it is.  A batch that does not divide the device count raises
+    ``ValueError``: a silent fallback would put every rollout on the first
+    device.
     """
     import jax
     from jax.sharding import Mesh, NamedSharding, PartitionSpec
 
-    devices = list(jax.devices()) if devices is None else list(devices)
     leaves = jax.tree_util.tree_leaves(tree)
-    if not leaves or len(devices) <= 1:
+    if not leaves or (devices is None and jax.device_count() == 1):
+        # left uncommitted: committed rollouts would commit the round's
+        # outputs, and round two would compile again for the new placement
         return tree
+    devices = list(jax.devices()) if devices is None else list(devices)
     B = int(leaves[0].shape[0])
     if B % len(devices) != 0:
-        return tree
+        raise ValueError(
+            f"batch {B} does not divide over {len(devices)} devices"
+        )
     mesh = Mesh(np.asarray(devices), ("rollout",))
     sharding = NamedSharding(mesh, PartitionSpec("rollout"))
     return jax.tree_util.tree_map(
@@ -232,6 +238,18 @@ def shard_rollouts(tree, devices=None):
         ),
         tree,
     )
+
+
+def _episode_sums(rew_hb) -> np.ndarray:
+    """Per-episode sums of a round's ``(H, B)`` step rewards, added step by
+    step in order.
+
+    The order is fixed by making the array row-major first.  A one-device
+    TPU array can come back from the device column-major, and numpy then
+    sums its contiguous step axis pairwise: one float32 ulp away from the
+    same rewards sharded over four chips, which arrive row-major.
+    """
+    return np.ascontiguousarray(rew_hb).sum(axis=0)
 
 
 # ----------------------------- the fused round -----------------------------
@@ -472,6 +490,7 @@ def train_dqn_batched(
     seed: int = 0,
     verbose: bool = False,
     tables: Optional[DeviceTables] = None,
+    devices: Optional[Sequence[Any]] = None,
 ) -> tuple:
     """Train the repartitioning DQN on device; returns (learner, stats).
 
@@ -483,7 +502,8 @@ def train_dqn_batched(
     :class:`DQNLearner` with the trained parameters, target network,
     optimizer state and update count installed — save/eval paths are
     identical to host training (the on-device replay ring is not carried
-    over).
+    over).  ``devices`` (default: all) are the devices the rollouts are
+    sharded over by :func:`shard_rollouts`; one device pins the trainer.
     """
     import jax
     import jax.numpy as jnp
@@ -579,13 +599,14 @@ def train_dqn_batched(
     )
     for r in range(rounds):
         jobs = round_jobs[r]
-        env0 = shard_rollouts(init_state(jobs, init_idx))
+        env0 = shard_rollouts(init_state(jobs, init_idx), devices)
         batch_arrays = shard_rollouts(
             tuple(
                 jnp.asarray(a)
                 for a in (jobs.arrival, jobs.deadline, jobs.rate_by_slots,
                           jobs.valid, jobs.edf_order, round_inv[r])
-            )
+            ),
+            devices,
         )
         t_r = time.time()  # lint: waive[DT002] per-round wall telemetry only
         (env, params, target, opt_state, replay, gstep, updates, key,
@@ -599,7 +620,7 @@ def train_dqn_batched(
         round_walls.append(time.time() - t_r)  # lint: waive[DT002] wall telemetry only
         round_steps.append(int(live_hb.sum()))
 
-        ep_rewards.extend(rew_hb.sum(axis=0).tolist())
+        ep_rewards.extend(_episode_sums(rew_hb).tolist())
         # ET proxy from the rollout accumulators, like the host loop's
         # per-episode `a * energy + avg_tardiness`
         for res in result_of(env, jobs, tables).to_sim_results():
@@ -609,7 +630,7 @@ def train_dqn_batched(
         if verbose:  # pragma: no cover
             print(
                 f"round {r + 1}/{rounds} episodes={B} "
-                f"mean_reward={rew_hb.sum(axis=0).mean():.2f} "
+                f"mean_reward={_episode_sums(rew_hb).mean():.2f} "
                 f"env_steps={int(gstep)} updates={int(updates)} "
                 f"wall={round_walls[-1]:.1f}s"
             )

@@ -21,10 +21,7 @@ __all__ = ["hint"]
 
 
 def _mesh_axes():
-    try:
-        mesh = jax.sharding.get_abstract_mesh()
-    except Exception:  # pragma: no cover - very old jax
-        return None
+    mesh = jax.sharding.get_abstract_mesh()
     if mesh is None or not mesh.axis_names:
         return None
     return mesh
@@ -39,7 +36,8 @@ def hint(x: jax.Array, *axes) -> jax.Array:
     shape = dict(zip(names, mesh.shape.values(), strict=True)) if hasattr(mesh, "shape") else {}
 
     spec = []
-    for dim, ax in zip(x.shape, axes, strict=True):
+    # fewer axes than dims is allowed: the tail is padded with None below
+    for dim, ax in zip(x.shape, axes, strict=False):
         if ax == "dp":
             cand = tuple(a for a in ("pod", "data") if a in names)
             ax = cand if len(cand) > 1 else (cand[0] if cand else None)

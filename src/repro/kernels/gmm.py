@@ -20,7 +20,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.compat import tpu_compiler_params
 
 __all__ = ["gmm"]
 
@@ -92,7 +91,7 @@ def gmm(
         kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((M, N), lhs.dtype),
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,

@@ -22,7 +22,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.compat import tpu_compiler_params
 
 __all__ = ["mamba_scan"]
 
@@ -102,7 +101,7 @@ def mamba_scan(
         out_specs=pl.BlockSpec((1, ch, bDi), lambda b, di, c: (b, c, di)),
         out_shape=jax.ShapeDtypeStruct((B, T, Di), x.dtype),
         scratch_shapes=[pltpu.VMEM((bDi, N), jnp.float32)],
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,

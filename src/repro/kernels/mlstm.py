@@ -29,7 +29,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.compat import tpu_compiler_params
 
 __all__ = ["mlstm_chunkwise"]
 
@@ -151,7 +150,7 @@ def mlstm_chunkwise(
             pltpu.VMEM((1, D), jnp.float32),
             pltpu.VMEM((1, 1), jnp.float32),
         ],
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary"),
         ),
         interpret=interpret,

@@ -44,7 +44,7 @@ from repro.distributed.sharding import (
     param_shardings,
 )
 from repro.distributed.step import make_prefill_step, make_serve_step, make_train_step
-from repro.launch.mesh import make_production_mesh, set_ambient_mesh
+from repro.launch.mesh import make_production_mesh
 from repro.launch.shapes import SHAPES, ShapeSpec, accum_steps_for, cell_applicable
 from repro.models import abstract_params, init_cache
 from repro.models.config import ArchConfig
@@ -215,7 +215,7 @@ def lower_cell(
             step, in_shardings=shards, donate_argnums=(1,) if donate else ()
         )
 
-    set_ambient_mesh(mesh)  # populates the abstract mesh for hints
+    jax.sharding.set_mesh(mesh)  # populates the abstract mesh for hints
     with mesh:
         lowered = jitted.lower(*args)
         rec: Dict[str, Any] = {"lower_seconds": time.time() - t0}

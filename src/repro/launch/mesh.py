@@ -12,40 +12,20 @@ from typing import Optional, Tuple
 
 import jax
 import numpy as np
-from jax.sharding import Mesh
+from jax.sharding import AxisType, Mesh
 
-__all__ = ["make_production_mesh", "make_smoke_mesh", "set_ambient_mesh", "POD_SHAPE"]
+__all__ = ["make_production_mesh", "make_smoke_mesh", "POD_SHAPE"]
 
 POD_SHAPE = (16, 16)  # one v5e pod: 256 chips
-
-
-def _axis_types_kwargs(n_axes: int) -> dict:
-    # jax >= 0.5 grows Mesh(axis_types=...); Auto is that API's default and the
-    # only behavior older jax has, so on old jax we simply omit the argument.
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is None:
-        return {}
-    return {"axis_types": (axis_type.Auto,) * n_axes}
-
-
-def set_ambient_mesh(mesh: Mesh) -> None:
-    """Populate the ambient/abstract mesh (feeds ``repro.distributed.hints``).
-
-    ``jax.sharding.set_mesh`` only exists on jax >= 0.5; on older jax the
-    hints layer already degrades to a no-op, and all real placement goes
-    through explicit ``device_put`` shardings + ``with mesh:`` contexts, so
-    skipping the call preserves behavior.
-    """
-    set_fn = getattr(jax.sharding, "set_mesh", None)
-    if set_fn is not None:
-        set_fn(mesh)
 
 
 def make_production_mesh(*, multi_pod: bool = False) -> Mesh:
     """The assignment's production mesh: 16x16 per pod, 2 pods multi-pod."""
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes, **_axis_types_kwargs(len(axes)))
+    # Auto axes: hints.py steers GSPMD propagation, and jax.make_mesh
+    # would otherwise default to Explicit
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_smoke_mesh(
@@ -61,4 +41,4 @@ def make_smoke_mesh(
             model *= 2
     assert data * model <= n, (data, model, n)
     devs = np.asarray(jax.devices()[: data * model]).reshape(data, model)
-    return Mesh(devs, ("data", "model"), **_axis_types_kwargs(2))
+    return Mesh(devs, ("data", "model"), axis_types=(AxisType.Auto,) * 2)

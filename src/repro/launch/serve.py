@@ -16,7 +16,7 @@ import numpy as np
 
 from repro.configs import get_config, smoke_config
 from repro.distributed.sharding import cache_shardings, param_shardings
-from repro.launch.mesh import make_production_mesh, make_smoke_mesh, set_ambient_mesh
+from repro.launch.mesh import make_production_mesh, make_smoke_mesh
 from repro.models import decode_step, init_cache, init_params
 
 
@@ -32,7 +32,7 @@ def serve(
 ) -> float:
     cfg = smoke_config(arch) if smoke else get_config(arch)
     mesh = make_production_mesh() if production_mesh else make_smoke_mesh()
-    set_ambient_mesh(mesh)
+    jax.sharding.set_mesh(mesh)
 
     params = init_params(cfg, seed=seed)
     params = jax.device_put(params, param_shardings(params, mesh))

@@ -23,7 +23,7 @@ from repro.configs import get_config, smoke_config
 from repro.data.pipeline import SyntheticLM
 from repro.distributed.sharding import batch_shardings, param_shardings
 from repro.distributed.step import make_train_step
-from repro.launch.mesh import make_production_mesh, make_smoke_mesh, set_ambient_mesh
+from repro.launch.mesh import make_production_mesh, make_smoke_mesh
 from repro.models import init_params
 from repro.optim import AdamW, AdamWConfig, linear_warmup_cosine
 
@@ -50,7 +50,7 @@ def train(
     mesh = (
         make_production_mesh() if production_mesh else make_smoke_mesh()
     )
-    set_ambient_mesh(mesh)
+    jax.sharding.set_mesh(mesh)
 
     opt = AdamW(
         AdamWConfig(lr=linear_warmup_cosine(lr, max(steps // 20, 1), steps))

@@ -22,6 +22,7 @@ import dataclasses
 import json
 import multiprocessing
 import os
+import sys
 import time
 from typing import Any, Callable, Dict, List, Optional, Sequence, Union
 
@@ -55,6 +56,22 @@ class SweepOutcome:
 def _strip_volatile(result: Dict[str, Any]) -> Dict[str, Any]:
     """Drop wall-clock noise so artifacts/cache entries diff cleanly."""
     return {k: v for k, v in result.items() if k != "elapsed_s"}
+
+
+def _hold_jax_to_cpu() -> None:
+    """Pool initializer: a worker's JAX (a ``dqn`` cell's learner) runs on
+    the CPU.
+
+    Oracle cells are host work, and an accelerator belongs to one process:
+    the parent, which runs the batched cells, already holds it.  The
+    environment variable covers a later ``import jax``; the config update
+    covers a worker whose ``__main__`` (re-imported by spawn) imported jax
+    already, since no JAX backend has started yet at this point.
+    """
+    os.environ["JAX_PLATFORMS"] = "cpu"
+    jax = sys.modules.get("jax")
+    if jax is not None:
+        jax.config.update("jax_platforms", "cpu")
 
 
 def run_cells(
@@ -150,7 +167,8 @@ def run_cells(
             # Workers only import the numpy-based core, so spawn stays cheap.
             ctx = multiprocessing.get_context("spawn")
             with concurrent.futures.ProcessPoolExecutor(
-                max_workers=max_workers, mp_context=ctx
+                max_workers=max_workers, mp_context=ctx,
+                initializer=_hold_jax_to_cpu,
             ) as ex:
                 futs = {ex.submit(run_cell, cells[i]): i for i in pending}
                 done = 0

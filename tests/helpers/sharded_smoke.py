@@ -28,13 +28,13 @@ def main() -> int:
         param_shardings,
     )
     from repro.distributed.step import make_serve_step, make_train_step
-    from repro.launch.mesh import make_smoke_mesh, set_ambient_mesh
+    from repro.launch.mesh import make_smoke_mesh
     from repro.models import init_cache, init_params
     from repro.optim import AdamW, AdamWConfig
 
     assert jax.device_count() == 8, jax.device_count()
     mesh = make_smoke_mesh(4, 2)
-    set_ambient_mesh(mesh)
+    jax.sharding.set_mesh(mesh)
 
     cfg = smoke_config("mixtral_8x7b")  # MoE + SWA exercises EP + ring caches
     params = init_params(cfg, seed=0)

@@ -7,8 +7,11 @@ tolerances.  The agreement matrix here *is* that contract's enforcement —
 scenario × policy × repartition-mode combos, each batching several seeds
 into one vectorized rollout and comparing per-seed against fresh oracle
 runs.  Tolerance values mirror BATCHED_SIM.md §4; tightening them requires
-re-measuring, loosening them requires a documented divergence source.
+re-measuring, loosening them requires a documented divergence source
+(the table lives in :mod:`repro.core.batched.agreement`).
 """
+
+import dataclasses
 
 import hypothesis
 import numpy as np
@@ -21,6 +24,7 @@ from repro.core.batched import (
     BatchedJobs,
     BatchedRepartitionEnv,
     UnsupportedPolicyError,
+    agreement_failures,
     build_tables,
     compile_policy,
     held_policy,
@@ -44,18 +48,6 @@ if hasattr(hypothesis, "HealthCheck"):  # the stub has no HealthCheck
     _SETTINGS["suppress_health_check"] = list(hypothesis.HealthCheck)
 
 
-# ----------------------------------------------------------------------
-# agreement tolerances (BATCHED_SIM.md §4; measured at dt=0.5)
-
-ENERGY_RTOL = 0.03
-TARDINESS_ATOL_MIN = 0.15  # minutes of avg tardiness, OR ...
-TARDINESS_RTOL = 0.5  # ... relative to max(oracle, TARDINESS_FLOOR)
-TARDINESS_FLOOR = 0.25
-BUSY_RTOL = 0.025
-PREEMPTIONS_RTOL = 0.4  # relative to max(oracle, PREEMPTIONS_FLOOR)
-PREEMPTIONS_FLOOR = 10.0
-
-
 def _oracle(jobs, policy, repartition_mode="partial"):
     sim = MIGSimulator(
         make_scheduler("EDF-FS"), repartition_mode=repartition_mode
@@ -66,21 +58,61 @@ def _oracle(jobs, policy, repartition_mode="partial"):
 
 
 def _assert_agreement(b, o, label=""):
-    """One rollout's batched aggregates vs its oracle run."""
-    assert b.num_jobs == o.num_jobs, label
-    assert b.repartitions == o.repartitions, label
-    assert b.energy_wh == pytest.approx(o.energy_wh, rel=ENERGY_RTOL), label
-    d_tard = abs(b.avg_tardiness - o.avg_tardiness)
-    assert (
-        d_tard <= TARDINESS_ATOL_MIN
-        or d_tard <= TARDINESS_RTOL * max(o.avg_tardiness, TARDINESS_FLOOR)
-    ), f"{label}: avg_tardiness {b.avg_tardiness} vs {o.avg_tardiness}"
-    assert b.busy_slot_minutes == pytest.approx(
-        o.busy_slot_minutes, rel=BUSY_RTOL, abs=1.0
-    ), label
-    assert abs(b.preemptions - o.preemptions) <= PREEMPTIONS_RTOL * max(
-        o.preemptions, PREEMPTIONS_FLOOR
-    ), f"{label}: preemptions {b.preemptions} vs {o.preemptions}"
+    """One rollout's batched aggregates vs its oracle run (BATCHED_SIM.md §4)."""
+    failures = agreement_failures(b, o)
+    assert not failures, f"{label}: {failures}"
+
+
+@pytest.mark.parametrize(
+    "field,value,column",
+    [
+        (None, None, None),
+        ("num_jobs", 101, "num_jobs"),
+        ("repartitions", 5, "repartitions"),
+        ("energy_wh", 1000.0 * 1.029, None),
+        ("energy_wh", 1000.0 * 1.031, "energy_wh"),
+        ("avg_tardiness", 2.0 + 1.0, None),  # 50% of 2.0
+        ("avg_tardiness", 2.0 + 1.01, "avg_tardiness"),
+        ("busy_slot_minutes", 400.0 + 9.9, None),  # 2.5% of 400 is 10
+        ("busy_slot_minutes", 400.0 + 10.1, "busy_slot_minutes"),
+        ("preemptions", 50 + 19, None),  # 40% of 50 is 20
+        ("preemptions", 50 + 21, "preemptions"),
+    ],
+)
+def test_agreement_failures_names_each_column(field, value, column):
+    """The §4 table: each column fails just past its tolerance, not before."""
+    from repro.core.metrics import SimResult
+
+    oracle = SimResult(
+        energy_wh=1000.0, avg_tardiness=2.0, num_jobs=100,
+        total_tardiness=200.0, preemptions=50, repartitions=4,
+        max_tardiness=9.0, deadline_misses=20, busy_slot_minutes=400.0,
+    )
+    batched = dataclasses.replace(oracle, **({field: value} if field else {}))
+    failures = agreement_failures(batched, oracle)
+    if column is None:
+        assert failures == []
+    else:
+        assert len(failures) == 1 and failures[0].startswith(column), failures
+
+
+def test_agreement_floors_for_light_days():
+    """Near-idle rollouts compare tardiness and preemptions above a floor."""
+    from repro.core.metrics import SimResult
+
+    oracle = SimResult(
+        energy_wh=10.0, avg_tardiness=0.0, num_jobs=3, total_tardiness=0.0,
+        preemptions=0, repartitions=0, max_tardiness=0.0, deadline_misses=0,
+        busy_slot_minutes=0.5,
+    )
+    ok = dataclasses.replace(
+        oracle, avg_tardiness=0.14, preemptions=3, busy_slot_minutes=1.4
+    )
+    assert agreement_failures(ok, oracle) == []
+    bad = dataclasses.replace(ok, avg_tardiness=0.16, preemptions=5)
+    assert [f.split()[0] for f in agreement_failures(bad, oracle)] == [
+        "avg_tardiness", "preemptions",
+    ]
 
 
 def _policy_of(name):

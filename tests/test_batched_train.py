@@ -16,6 +16,8 @@ Four layers, mirroring the two-backend discipline of test_batched.py:
 
 import json
 import os
+import subprocess
+import sys
 
 import jax
 import jax.numpy as jnp
@@ -32,6 +34,10 @@ from repro.core.rl.batched_train import (
 )
 
 BASELINES = os.path.join(os.path.dirname(__file__), "..", "benchmarks", "baselines")
+SHARDED_HELPER = os.path.join(
+    os.path.dirname(__file__), "helpers", "sharded_rollouts.py"
+)
+SRC = os.path.abspath(os.path.join(os.path.dirname(__file__), "..", "src"))
 
 
 def _cfg(**kw):
@@ -287,9 +293,47 @@ def test_train_dqn_backend_dispatch_validation():
 
 
 def test_shard_rollouts_single_device_noop():
-    tree = {"a": jnp.zeros((4, 3)), "b": jnp.zeros((7,))}
-    out = shard_rollouts(tree, devices=jax.devices()[:1])
-    assert out is tree  # identity on one device
+    dev = jax.devices()[0]
+    tree = {"a": jnp.arange(12.0).reshape(4, 3), "b": jnp.zeros((7,))}
+    out = shard_rollouts(tree, devices=[dev])
+    # one device holds the whole batch; values and other leaves unchanged
+    assert out["a"].sharding.device_set == {dev}
+    np.testing.assert_array_equal(np.asarray(out["a"]), np.asarray(tree["a"]))
+    assert out["b"] is tree["b"]
+
+
+def test_episode_sums_do_not_depend_on_memory_layout():
+    """A device may hand a round's (H, B) rewards back column-major; numpy
+    would then sum the step axis pairwise instead of in step order."""
+    from repro.core.rl.batched_train import _episode_sums
+
+    rng = np.random.default_rng(0)
+    rew = (rng.normal(size=(104, 64)) * 1e3).astype(np.float32)
+    col_major = np.asfortranarray(rew)
+    # the hazard is real for these rewards ...
+    assert not np.array_equal(col_major.sum(axis=0), rew.sum(axis=0))
+    # ... and the trainer's sums take one order for both layouts
+    np.testing.assert_array_equal(_episode_sums(col_major), _episode_sums(rew))
+    np.testing.assert_array_equal(_episode_sums(rew), rew.sum(axis=0))
+
+
+def test_sharded_rollouts_match_one_device():
+    """On four virtual CPU devices (own process: the device count is fixed
+    before jax starts), a training round with its rollouts sharded by
+    ``shard_rollouts`` equals the one-device round, and a batch that does
+    not divide the device count is refused."""
+    env = dict(os.environ)
+    env["PYTHONPATH"] = SRC
+    env["JAX_PLATFORMS"] = "cpu"
+    env.pop("XLA_FLAGS", None)
+    proc = subprocess.run(
+        [sys.executable, SHARDED_HELPER],
+        capture_output=True, text=True, env=env, timeout=600,
+    )
+    assert proc.returncode == 0, (
+        f"stdout:\n{proc.stdout}\nstderr:\n{proc.stderr[-3000:]}"
+    )
+    assert "SHARDED_ROLLOUTS_OK" in proc.stdout
 
 
 def test_rl_baseline_claim_and_params_probe():

@@ -1,6 +1,8 @@
 """Sweep engine: hashing determinism, cache behavior, worker independence."""
 
+import concurrent.futures
 import json
+import multiprocessing
 import os
 
 import pytest
@@ -304,6 +306,27 @@ def test_worker_count_independence_and_jsonl_artifact(tmp_path):
     assert [rec["cell"]["seed"] for rec in lines] == [c["seed"] for c in cells]
     # volatile timing never leaks into the artifact
     assert all("elapsed_s" not in rec["result"] for rec in lines)
+
+
+def _worker_jax_platforms(cell, policy_factory=None):
+    import jax
+
+    return {"env": os.environ.get("JAX_PLATFORMS"),
+            "config": jax.config.jax_platforms}
+
+
+def test_pool_workers_hold_jax_to_cpu(monkeypatch):
+    """Pool workers run host work: their JAX must not ask for the
+    accelerator the parent process holds (a dqn cell builds a learner).
+    The workers' cell runner is swapped for one that reports the platform
+    its JAX was given."""
+    from repro.sweep import runner
+
+    monkeypatch.delenv("JAX_PLATFORMS", raising=False)
+    monkeypatch.setattr(runner, "run_cell", _worker_jax_platforms)
+    out = run_cells("t", _tiny_cells(2), workers=2, cache=False,
+                    artifacts_dir=None)
+    assert out.results == [{"env": "cpu", "config": "cpu"}] * 2
 
 
 def test_parallel_failure_reports_cell(tmp_path):
