@@ -31,10 +31,13 @@ discretization and float32 accumulation.
 from __future__ import annotations
 
 import functools
-from typing import Any, Dict, NamedTuple, Optional
+import re
+from typing import Any, Dict, NamedTuple, Optional, Tuple
 
 import numpy as np
 
+# lint: waive[VG001] spans and named scopes only: no semantic change; batched bit-identity suites pin it
+from repro import obs
 from repro.core.batched.policies import BatchedPolicy
 from repro.core.batched.state import BatchedJobs, BatchedResult
 from repro.core.batched.tables import DeviceTables, build_tables
@@ -43,10 +46,13 @@ from repro.core.simulator import REPARTITION_MODES
 __all__ = [
     "DEFAULT_DT_MIN",
     "DEFAULT_CHUNK_STEPS",
+    "STEP_PHASES",
     "RolloutState",
+    "chunk_op_scopes",
     "device_constants",
     "init_state",
     "make_step_fn",
+    "op_phases",
     "run_steps",
     "simulate_batch",
     "result_of",
@@ -67,6 +73,13 @@ _W_EPS = 1e-6
 #: job-axis block size for the two-level EDF rank search; J must be a
 #: multiple of this (BatchedJobs pads to PAD_MULTIPLE == _BLOCK).
 _BLOCK = 32
+
+#: the phases of one step, in order, each a ``jax.named_scope`` of
+#: :func:`make_step_fn`: 1, 2, 3 (the EDF rank search and reassignment),
+#: 4, 4b without its write-back, the merged write-back scatters, the
+#: rollout end detection, and 5.  Scopes change op metadata only.
+STEP_PHASES = ("repartition", "policy", "edf_rank", "advance", "handoff",
+               "writeback", "end_detect", "accounting")
 
 
 class RolloutState(NamedTuple):
@@ -168,7 +181,12 @@ def make_step_fn(kind: str, dt: float, penalty: float,
     agent trains against the very physics its rollouts are evaluated on.
     The cache key mirrors :func:`_chunk_fn` minus the step count.
     """
+    import jax
     import jax.numpy as jnp
+
+    repartition, policy, edf_rank, advance, handoff, writeback, end_detect, accounting = (
+        STEP_PHASES
+    )
 
     def step_one(carry, t, arrival, deadline, rates, valid, dorder,
                  primary, secondary,
@@ -186,181 +204,190 @@ def make_step_fn(kind: str, dt: float, penalty: float,
         i32 = jnp.int32
 
         # -- 1. an elapsed repartition completes ------------------------
-        in_flight = pending != cfg
-        finish = in_flight & (stall_left <= _T_EPS)
-        surv = o2n[cfg, pending]  # (S,) old->new survivor indices
-        occ = slice_job >= 0
-        keep = finish & occ & (surv >= 0)
-        remapped = jnp.full((S,), -1, i32).at[
-            jnp.where(keep, surv, S)
-        ].set(jnp.where(keep, slice_job, -1), mode="drop")
-        slice_job = jnp.where(finish, remapped, slice_job)
-        cfg = jnp.where(finish, pending, cfg)
+        with jax.named_scope(repartition):
+            in_flight = pending != cfg
+            finish = in_flight & (stall_left <= _T_EPS)
+            surv = o2n[cfg, pending]  # (S,) old->new survivor indices
+            occ = slice_job >= 0
+            keep = finish & occ & (surv >= 0)
+            remapped = jnp.full((S,), -1, i32).at[
+                jnp.where(keep, surv, S)
+            ].set(jnp.where(keep, slice_job, -1), mode="drop")
+            slice_job = jnp.where(finish, remapped, slice_job)
+            cfg = jnp.where(finish, pending, cfg)
 
         # -- 2. policy decision (never mid-flight, never past stop) -----
-        in_flight = pending != cfg
-        if kind == "daynight":
-            tod = jnp.mod(t, _DAY)
-            is_day = (tod >= day_start) & (tod < day_end)
-            target = jnp.where(is_day, primary, secondary)
-        else:
-            target = primary
-        want = (~in_flight) & (t <= stop_time + _T_EPS) & (target != cfg)
-        surv_t = o2n[cfg, target]  # (S,)
-        kill = want & (slice_job >= 0) & (surv_t < 0)
-        pre = pre + jnp.sum(kill).astype(i32)
-        slice_job = jnp.where(kill, -1, slice_job)
-        pending = jnp.where(want, target, pending)
-        stall_left = jnp.where(want, jnp.float32(penalty), stall_left)
-        rep = rep + want.astype(i32)
-        in_flight = pending != cfg
+        with jax.named_scope(policy):
+            in_flight = pending != cfg
+            if kind == "daynight":
+                tod = jnp.mod(t, _DAY)
+                is_day = (tod >= day_start) & (tod < day_end)
+                target = jnp.where(is_day, primary, secondary)
+            else:
+                target = primary
+            want = (~in_flight) & (t <= stop_time + _T_EPS) & (target != cfg)
+            surv_t = o2n[cfg, target]  # (S,)
+            kill = want & (slice_job >= 0) & (surv_t < 0)
+            pre = pre + jnp.sum(kill).astype(i32)
+            slice_job = jnp.where(kill, -1, slice_job)
+            pending = jnp.where(want, target, pending)
+            stall_left = jnp.where(want, jnp.float32(penalty), stall_left)
+            rep = rep + want.astype(i32)
+            in_flight = pending != cfg
 
         # -- 3. EDF-FS reassignment (frozen while repartitioning) -------
-        # first 2S in-system jobs in EDF order: permute the in-system mask
-        # by the static deadline order, then find the first 2S set bits with
-        # a two-level rank search — per-block popcounts, a short cumsum over
-        # blocks, and an intra-block scan only for the <= 2S hit blocks.
-        # (A full-J cumsum or an O(J)-update scatter here dominates the
-        # whole step on CPU XLA.)
-        insys = (arrival <= t + _T_EPS) & (remaining > _W_EPS) & valid
-        m = insys[dorder]
-        NB = J // _BLOCK
-        mb = m.reshape(NB, _BLOCK)
-        bc = jnp.cumsum(jnp.sum(mb, axis=1, dtype=i32))  # (NB,)
-        ranks = jnp.arange(1, 2 * S + 1, dtype=i32)
-        blk = jnp.searchsorted(bc, ranks)  # first block with cum >= rank
-        blkc = jnp.clip(blk, 0, NB - 1)
-        prev = jnp.where(blk > 0, bc[jnp.maximum(blk - 1, 0)], 0)
-        sub = mb[blkc]  # (2S, BLOCK)
-        sc = jnp.cumsum(sub.astype(i32), axis=1)
-        need = (ranks - prev)[:, None]
-        off = jnp.argmax(sub & (sc == need), axis=1)
-        pos = blkc * _BLOCK + off
-        cand = jnp.where(blk < NB, dorder[pos], J)
-        ranked = slice_rank[cfg]  # (S,) slice ids fastest-first, -1 padded
-        rv = (ranked >= 0) & (cand[:S] < J)
-        proposed = jnp.full((S,), -1, i32).at[
-            jnp.where(rv, ranked, S)
-        ].set(jnp.where(rv, cand[:S], -1), mode="drop")
-        new_sj = jnp.where(in_flight, slice_job, proposed)
-        moved = (slice_job >= 0) & (new_sj != slice_job) & (~in_flight)
-        pre = pre + jnp.sum(moved).astype(i32)
-        slice_job = new_sj
+        with jax.named_scope(edf_rank):
+            # first 2S in-system jobs in EDF order: permute the in-system mask
+            # by the static deadline order, then find the first 2S set bits with
+            # a two-level rank search — per-block popcounts, a short cumsum over
+            # blocks, and an intra-block scan only for the <= 2S hit blocks.
+            # (A full-J cumsum or an O(J)-update scatter here dominates the
+            # whole step on CPU XLA.)
+            insys = (arrival <= t + _T_EPS) & (remaining > _W_EPS) & valid
+            m = insys[dorder]
+            NB = J // _BLOCK
+            mb = m.reshape(NB, _BLOCK)
+            bc = jnp.cumsum(jnp.sum(mb, axis=1, dtype=i32))  # (NB,)
+            ranks = jnp.arange(1, 2 * S + 1, dtype=i32)
+            blk = jnp.searchsorted(bc, ranks)  # first block with cum >= rank
+            blkc = jnp.clip(blk, 0, NB - 1)
+            prev = jnp.where(blk > 0, bc[jnp.maximum(blk - 1, 0)], 0)
+            sub = mb[blkc]  # (2S, BLOCK)
+            sc = jnp.cumsum(sub.astype(i32), axis=1)
+            need = (ranks - prev)[:, None]
+            off = jnp.argmax(sub & (sc == need), axis=1)
+            pos = blkc * _BLOCK + off
+            cand = jnp.where(blk < NB, dorder[pos], J)
+            ranked = slice_rank[cfg]  # (S,) slice ids fastest-first, -1 padded
+            rv = (ranked >= 0) & (cand[:S] < J)
+            proposed = jnp.full((S,), -1, i32).at[
+                jnp.where(rv, ranked, S)
+            ].set(jnp.where(rv, cand[:S], -1), mode="drop")
+            new_sj = jnp.where(in_flight, slice_job, proposed)
+            moved = (slice_job >= 0) & (new_sj != slice_job) & (~in_flight)
+            pre = pre + jnp.sum(moved).astype(i32)
+            slice_job = new_sj
 
         # -- 4. advance dt ----------------------------------------------
-        run = slice_job >= 0
-        sjc = jnp.clip(slice_job, 0, J - 1)
-        slots_of = slice_slots[cfg]  # (S,)
-        slot_s = jnp.where(run, slots_of, 0)
-        rem_s = remaining[sjc]
-        rate_s = rates[sjc, slot_s]
-        fin = jnp.where(run & (rate_s > 0),
-                        rem_s / jnp.maximum(rate_s, 1e-12), jnp.inf)
-        run_time = jnp.where(run, jnp.minimum(fin, dt), 0.0)
-        done = run & (fin <= dt + _T_EPS)
-        comp_t = t + fin
-        new_rem_s = jnp.where(done, 0.0,
-                              jnp.maximum(rem_s - rate_s * dt, 0.0))
-        # (J,)-array writes are deferred and merged with the handoff's into
-        # one scatter per array — scatters carry a large fixed cost on CPU
-        busy_minutes = jnp.sum(slot_s * run_time)
+        with jax.named_scope(advance):
+            run = slice_job >= 0
+            sjc = jnp.clip(slice_job, 0, J - 1)
+            slots_of = slice_slots[cfg]  # (S,)
+            slot_s = jnp.where(run, slots_of, 0)
+            rem_s = remaining[sjc]
+            rate_s = rates[sjc, slot_s]
+            fin = jnp.where(run & (rate_s > 0),
+                            rem_s / jnp.maximum(rate_s, 1e-12), jnp.inf)
+            run_time = jnp.where(run, jnp.minimum(fin, dt), 0.0)
+            done = run & (fin <= dt + _T_EPS)
+            comp_t = t + fin
+            new_rem_s = jnp.where(done, 0.0,
+                                  jnp.maximum(rem_s - rate_s * dt, 0.0))
+            # (J,)-array writes are deferred and merged with the handoff's into
+            # one scatter per array — scatters carry a large fixed cost on CPU
+            busy_minutes = jnp.sum(slot_s * run_time)
 
-        # tardiness: each in-system job accrues overlap of its busy/waiting
-        # span with [deadline, inf); jobs completing mid-step get the
-        # overshoot past their exact completion refunded (S-space)
-        tard = tard + jnp.sum(jnp.where(
-            insys, jnp.maximum(t + dt - jnp.maximum(deadline, t), 0.0), 0.0
-        ))
-        base_s = jnp.maximum(deadline[sjc], t)
-        over = jnp.where(done,
-                         jnp.maximum(t + dt - base_s, 0.0)
-                         - jnp.maximum(comp_t - base_s, 0.0), 0.0)
-        tard = tard - jnp.sum(over)
-        held = slice_job  # lane->job ids before done lanes are cleared
-        slice_job = jnp.where(done, -1, slice_job)
+            # tardiness: each in-system job accrues overlap of its busy/waiting
+            # span with [deadline, inf); jobs completing mid-step get the
+            # overshoot past their exact completion refunded (S-space)
+            tard = tard + jnp.sum(jnp.where(
+                insys, jnp.maximum(t + dt - jnp.maximum(deadline, t), 0.0), 0.0
+            ))
+            base_s = jnp.maximum(deadline[sjc], t)
+            over = jnp.where(done,
+                             jnp.maximum(t + dt - base_s, 0.0)
+                             - jnp.maximum(comp_t - base_s, 0.0), 0.0)
+            tard = tard - jnp.sum(over)
+            held = slice_job  # lane->job ids before done lanes are cleared
+            slice_job = jnp.where(done, -1, slice_job)
 
         # -- 4b. same-step handoff of freed capacity --------------------
-        # the oracle reassigns at the completion event; without this pass a
-        # deep queue on few slices loses up to dt per handoff and the error
-        # compounds down the queue.  One round per step (no cascading):
-        # the r-th freed slice (fastest-first) runs the r-th waiting job
-        # (EDF-first: candidates num_slices.. of the buffer built above).
-        leftover = jnp.where(done & (~in_flight), dt - run_time, 0.0)
-        nsl = num_slices[cfg]
-        fr = jnp.where(ranked >= 0,
-                       leftover[jnp.clip(ranked, 0, S - 1)], 0.0)
-        has = fr > _T_EPS
-        hrk = jnp.cumsum(has.astype(i32))
-        hpos = jnp.where(has, hrk - 1, S)
-        fslice = jnp.full((S,), -1, i32).at[hpos].set(
-            jnp.where(has, ranked, -1), mode="drop")
-        fgive = jnp.zeros((S,), jnp.float32).at[hpos].set(
-            jnp.where(has, fr, 0.0), mode="drop")
-        wjob = cand[jnp.clip(nsl + jnp.arange(S, dtype=i32), 0, 2 * S - 1)]
-        wok = (fslice >= 0) & (wjob < J)
-        wjc = jnp.clip(wjob, 0, J - 1)
-        w_rem = remaining[wjc]  # they were waiting: untouched by phase 4
-        slot_w = slots_of[jnp.clip(fslice, 0, S - 1)]
-        rate_w = rates[wjc, jnp.where(wok, slot_w, 0)]
-        fin_w = jnp.where(wok & (rate_w > 0),
-                          w_rem / jnp.maximum(rate_w, 1e-12), jnp.inf)
-        h_done = wok & (fin_w <= fgive + _T_EPS)
-        tc = (t + dt - fgive) + fin_w
-        new_wrem = jnp.where(h_done, 0.0,
-                             jnp.maximum(w_rem - rate_w * fgive, 0.0))
-        # merged write-back: running jobs (phase 4) and handoff jobs touch
-        # disjoint index sets, so one (2S,) scatter per array suffices
-        rem_idx = jnp.concatenate([jnp.where(run, held, J),
-                                   jnp.where(wok, wjob, J)])
-        remaining = remaining.at[rem_idx].set(
-            jnp.concatenate([new_rem_s, new_wrem]), mode="drop")
-        comp_idx = jnp.concatenate([jnp.where(done, held, J),
-                                    jnp.where(h_done, wjob, J)])
-        completion = completion.at[comp_idx].set(
-            jnp.concatenate([comp_t, tc]), mode="drop")
-        busy_minutes = busy_minutes + jnp.sum(jnp.where(
-            wok, slot_w * jnp.minimum(fin_w, fgive), 0.0))
-        # it accrued tardiness as waiting-to-step-end; completing at tc
-        # refunds the overshoot
-        base_w = jnp.maximum(deadline[wjc], t)
-        refund = (jnp.maximum(t + dt - base_w, 0.0)
-                  - jnp.maximum(tc - base_w, 0.0))
-        tard = tard - jnp.sum(jnp.where(h_done, refund, 0.0))
+        with jax.named_scope(handoff):
+            # the oracle reassigns at the completion event; without this pass a
+            # deep queue on few slices loses up to dt per handoff and the error
+            # compounds down the queue.  One round per step (no cascading):
+            # the r-th freed slice (fastest-first) runs the r-th waiting job
+            # (EDF-first: candidates num_slices.. of the buffer built above).
+            leftover = jnp.where(done & (~in_flight), dt - run_time, 0.0)
+            nsl = num_slices[cfg]
+            fr = jnp.where(ranked >= 0,
+                           leftover[jnp.clip(ranked, 0, S - 1)], 0.0)
+            has = fr > _T_EPS
+            hrk = jnp.cumsum(has.astype(i32))
+            hpos = jnp.where(has, hrk - 1, S)
+            fslice = jnp.full((S,), -1, i32).at[hpos].set(
+                jnp.where(has, ranked, -1), mode="drop")
+            fgive = jnp.zeros((S,), jnp.float32).at[hpos].set(
+                jnp.where(has, fr, 0.0), mode="drop")
+            wjob = cand[jnp.clip(nsl + jnp.arange(S, dtype=i32), 0, 2 * S - 1)]
+            wok = (fslice >= 0) & (wjob < J)
+            wjc = jnp.clip(wjob, 0, J - 1)
+            w_rem = remaining[wjc]  # they were waiting: untouched by phase 4
+            slot_w = slots_of[jnp.clip(fslice, 0, S - 1)]
+            rate_w = rates[wjc, jnp.where(wok, slot_w, 0)]
+            fin_w = jnp.where(wok & (rate_w > 0),
+                              w_rem / jnp.maximum(rate_w, 1e-12), jnp.inf)
+            h_done = wok & (fin_w <= fgive + _T_EPS)
+            tc = (t + dt - fgive) + fin_w
+            new_wrem = jnp.where(h_done, 0.0,
+                                 jnp.maximum(w_rem - rate_w * fgive, 0.0))
+        with jax.named_scope(writeback):
+            # merged write-back: running jobs (phase 4) and handoff jobs touch
+            # disjoint index sets, so one (2S,) scatter per array suffices
+            rem_idx = jnp.concatenate([jnp.where(run, held, J),
+                                       jnp.where(wok, wjob, J)])
+            remaining = remaining.at[rem_idx].set(
+                jnp.concatenate([new_rem_s, new_wrem]), mode="drop")
+            comp_idx = jnp.concatenate([jnp.where(done, held, J),
+                                        jnp.where(h_done, wjob, J)])
+            completion = completion.at[comp_idx].set(
+                jnp.concatenate([comp_t, tc]), mode="drop")
+        with jax.named_scope(handoff):
+            busy_minutes = busy_minutes + jnp.sum(jnp.where(
+                wok, slot_w * jnp.minimum(fin_w, fgive), 0.0))
+            # it accrued tardiness as waiting-to-step-end; completing at tc
+            # refunds the overshoot
+            base_w = jnp.maximum(deadline[wjc], t)
+            refund = (jnp.maximum(t + dt - base_w, 0.0)
+                      - jnp.maximum(tc - base_w, 0.0))
+            tard = tard - jnp.sum(jnp.where(h_done, refund, 0.0))
 
         # -- rollout end detection --------------------------------------
-        all_done = ~jnp.any(valid & (remaining > _W_EPS))
-        finishes = all_done & (~jnp.isfinite(stop_time))
-        e = jnp.maximum(jnp.maximum(
-            jnp.max(jnp.where(done, comp_t, -jnp.inf)),
-            jnp.max(jnp.where(h_done, tc, -jnp.inf))), t)
-        if kind == "daynight":
-            # the oracle still fires the one pending boundary timer after
-            # the last completion (idle until the boundary, then switches)
-            base = jnp.floor(e / _DAY) * _DAY
-            cands = jnp.stack([
-                base + day_start, base + day_end,
-                base + _DAY + day_start, base + _DAY + day_end,
-            ])
-            end_stop = jnp.min(jnp.where(cands > e + _T_EPS, cands, jnp.inf))
-        else:
-            end_stop = e
-        stop_time = jnp.where(finishes, end_stop, stop_time)
+        with jax.named_scope(end_detect):
+            all_done = ~jnp.any(valid & (remaining > _W_EPS))
+            finishes = all_done & (~jnp.isfinite(stop_time))
+            e = jnp.maximum(jnp.maximum(
+                jnp.max(jnp.where(done, comp_t, -jnp.inf)),
+                jnp.max(jnp.where(h_done, tc, -jnp.inf))), t)
+            if kind == "daynight":
+                # the oracle still fires the one pending boundary timer after
+                # the last completion (idle until the boundary, then switches)
+                base = jnp.floor(e / _DAY) * _DAY
+                cands = jnp.stack([
+                    base + day_start, base + day_end,
+                    base + _DAY + day_start, base + _DAY + day_end,
+                ])
+                end_stop = jnp.min(jnp.where(cands > e + _T_EPS, cands, jnp.inf))
+            else:
+                end_stop = e
+            stop_time = jnp.where(finishes, end_stop, stop_time)
 
         # -- 5. energy / busy / histogram over the accounted span -------
-        span = jnp.clip(jnp.minimum(t + dt, stop_time) - t, 0.0, dt)
-        busy_min = busy_min + busy_minutes
-        avg_busy = jnp.where(
-            span > _T_EPS, busy_minutes / jnp.maximum(span, _T_EPS), 0.0
-        )
-        lo = jnp.clip(jnp.floor(avg_busy).astype(i32), 0, max_slots)
-        hi = jnp.clip(lo + 1, 0, max_slots)
-        frac = jnp.clip(avg_busy - lo.astype(jnp.float32), 0.0, 1.0)
-        watts_now = watts[lo] * (1.0 - frac) + watts[hi] * frac
-        energy = energy + watts_now * span / 60.0
-        level = jnp.clip(jnp.sum(slot_s), 0, max_slots)
-        hist = hist.at[level].add(span)
+        with jax.named_scope(accounting):
+            span = jnp.clip(jnp.minimum(t + dt, stop_time) - t, 0.0, dt)
+            busy_min = busy_min + busy_minutes
+            avg_busy = jnp.where(
+                span > _T_EPS, busy_minutes / jnp.maximum(span, _T_EPS), 0.0
+            )
+            lo = jnp.clip(jnp.floor(avg_busy).astype(i32), 0, max_slots)
+            hi = jnp.clip(lo + 1, 0, max_slots)
+            frac = jnp.clip(avg_busy - lo.astype(jnp.float32), 0.0, 1.0)
+            watts_now = watts[lo] * (1.0 - frac) + watts[hi] * frac
+            energy = energy + watts_now * span / 60.0
+            level = jnp.clip(jnp.sum(slot_s), 0, max_slots)
+            hist = hist.at[level].add(span)
 
-        stall_left = jnp.maximum(stall_left - dt, 0.0)
+            stall_left = jnp.maximum(stall_left - dt, 0.0)
         return RolloutState(
             remaining, completion, slice_job, cfg, pending, stall_left,
             stop_time, energy, tard, busy_min, pre, rep, hist,
@@ -421,6 +448,7 @@ def run_steps(
     the compiled program is cached per (policy kind, dt, n_steps) so
     repeated calls with the same shapes are compile-free.
     """
+    import jax
     import jax.numpy as jnp
 
     if penalty_min is None:
@@ -432,20 +460,108 @@ def run_steps(
             f"padded job axis {jobs.padded_jobs} must be a multiple of "
             f"{_BLOCK} (use BatchedJobs.from_job_lists, which pads to it)"
         )
-    fn = _chunk_fn(
-        policy.kind, float(dt_min), int(n_steps), float(penalty_min),
-        float(policy.day_start), float(policy.day_end),
-    )
-    return fn(
-        state,
-        jnp.asarray(jobs.arrival), jnp.asarray(jobs.deadline),
-        jnp.asarray(jobs.rate_by_slots), jnp.asarray(jobs.valid),
-        jnp.asarray(jobs.edf_order),
-        jnp.asarray(policy.primary), jnp.asarray(policy.secondary),
-        jnp.float32(t0_min),
-        consts["slice_slots"], consts["slice_rank"], consts["num_slices"],
-        consts["old_to_new"], consts["watts"],
-    )
+    key = (policy.kind, float(dt_min), int(n_steps), float(penalty_min),
+           float(policy.day_start), float(policy.day_end))
+    host = (jobs.arrival, jobs.deadline, jobs.rate_by_slots, jobs.valid,
+            jobs.edf_order, policy.primary, policy.secondary)
+    nbytes = sum(a.nbytes for a in host if isinstance(a, np.ndarray))
+    with obs.span("chunk.upload", bytes=nbytes):
+        args = (
+            state, *(jnp.asarray(a) for a in host), jnp.float32(t0_min),
+            consts["slice_slots"], consts["slice_rank"], consts["num_slices"],
+            consts["old_to_new"], consts["watts"],
+        )
+    if obs.profiling():
+        shapes = (jobs.rate_by_slots.shape, np.shape(state.slice_job),
+                  np.shape(consts["old_to_new"]))  # B, J, K; S; configurations
+        if (key, shapes) not in _PROFILED_CHUNKS:
+            specs = jax.tree.map(lambda a: jax.ShapeDtypeStruct(np.shape(a), a.dtype), args)
+            _PROFILED_CHUNKS[key, shapes] = [key, specs, None]
+    with obs.span("chunk.dispatch"):
+        return _chunk_fn(*key)(*args)
+
+
+#: the chunk programs :func:`run_steps` dispatched while a profiler session
+#: ran: (``_chunk_fn`` key, shapes) -> [that key, argument ``ShapeDtypeStruct``s,
+#: op scopes once :func:`chunk_op_scopes` has made them]
+_PROFILED_CHUNKS: Dict[Tuple, list] = {}
+
+
+def chunk_op_scopes() -> Dict[str, str]:
+    """``{HLO instruction name: step phase}`` of the chunk programs that
+    :func:`run_steps` dispatched while a profiler session ran.
+
+    Each such program is built and compiled again from its argument shapes,
+    with JAX's persistent compilation cache off for the call: op metadata is
+    not part of that cache's key, so the executable that ran may have come
+    from a build without the scopes.  The instruction names are the same,
+    since scopes change metadata only.  An op outside every phase maps to
+    ``""`` (see :func:`op_phases`).
+    """
+    import jax
+    from jax.experimental.compilation_cache import compilation_cache
+
+    todo = [entry for entry in _PROFILED_CHUNKS.values() if entry[2] is None]
+    if todo:
+        was = jax.config.jax_enable_compilation_cache
+        jax.config.update("jax_enable_compilation_cache", False)
+        compilation_cache.reset_cache()
+        try:
+            for entry in todo:
+                fresh = _chunk_fn.__wrapped__(*entry[0])  # not the program in memory
+                entry[2] = op_phases(fresh.lower(*entry[1]).compile().as_text())
+        finally:
+            jax.config.update("jax_enable_compilation_cache", was)
+            compilation_cache.reset_cache()
+    out: Dict[str, str] = {}
+    for entry in _PROFILED_CHUNKS.values():
+        out.update(entry[2])
+    return out
+
+
+_HLO_COMPUTATION = re.compile(r"^(?:ENTRY )?%?(\S+) \(.*\{$")
+_HLO_INSTRUCTION = re.compile(r"^\s+(ROOT )?%?(\S+) = ")
+_HLO_FUSION_CALLS = re.compile(r" fusion\(.*\bcalls=%?([^\s,]+)")
+_HLO_TO_APPLY = re.compile(r"\bto_apply=%?([^\s,]+)")
+_HLO_OP_NAME = re.compile(r'op_name="([^"]*)"')
+_PHASE_SET = frozenset(STEP_PHASES)
+
+
+def _phase_of(line: str) -> str:
+    m = _HLO_OP_NAME.search(line)
+    names = re.split(r"[/()]", m.group(1)) if m else ()
+    return next((n for n in reversed(names) if n in _PHASE_SET), "")
+
+
+def op_phases(hlo_text: str) -> Dict[str, str]:
+    """``{instruction name: phase}`` for the top-level instructions of
+    compiled HLO text: those of every computation that is not fused into
+    an op or applied by one (a reducer, a scatter's combiner).
+
+    An instruction's phase is the innermost of :data:`STEP_PHASES` in its
+    ``op_name`` metadata; a fusion with none takes its fused root's.
+    """
+    comps: Dict[str, list] = {}  # computation -> [(name, is_root, phase, fused callee)]
+    inner = set(_HLO_TO_APPLY.findall(hlo_text))
+    current: list = []
+    for line in hlo_text.splitlines():
+        m = _HLO_COMPUTATION.match(line)
+        if m:
+            current = comps.setdefault(m.group(1), [])
+            continue
+        m = _HLO_INSTRUCTION.match(line)
+        if m:
+            calls = _HLO_FUSION_CALLS.search(line)
+            current.append((m.group(2), bool(m.group(1)), _phase_of(line),
+                            calls.group(1) if calls else None))
+    inner |= {c for insts in comps.values() for *_, c in insts if c}
+    root_phase = {name: next((p for _, r, p, _ in insts if r), "")
+                  for name, insts in comps.items()}
+    return {
+        inst: phase or (root_phase.get(callee, "") if callee else "")
+        for comp, insts in comps.items() if comp not in inner
+        for inst, _, phase, callee in insts
+    }
 
 
 def result_of(
@@ -513,21 +629,25 @@ def simulate_batch(
     bound = _horizon_bound(jobs) if max_minutes is None else float(max_minutes)
 
     steps_done = 0
-    while True:
-        state = run_steps(
-            state, jobs, policy, consts,
-            t0_min=steps_done * dt_min, n_steps=chunk_steps, dt_min=dt_min,
-            penalty_min=tables.penalty_min,
-        )
-        steps_done += chunk_steps
-        t_now = steps_done * dt_min
-        stop = np.asarray(state.stop_time)
-        if np.all(stop < t_now):
-            break
-        if t_now > bound:
-            raise RuntimeError(
-                f"batched rollout still live at t={t_now:.0f} min "
-                f"(bound {bound:.0f}); unfinished rollouts: "
-                f"{int(np.sum(~(stop < t_now)))}"
+    with obs.span("batched.simulate") as counts:
+        while True:
+            state = run_steps(
+                state, jobs, policy, consts,
+                t0_min=steps_done * dt_min, n_steps=chunk_steps, dt_min=dt_min,
+                penalty_min=tables.penalty_min,
             )
-    return result_of(state, jobs, tables)
+            steps_done += chunk_steps
+            t_now = steps_done * dt_min
+            with obs.span("chunk.sync"):
+                stop = np.asarray(state.stop_time)
+            if np.all(stop < t_now):
+                break
+            if t_now > bound:
+                raise RuntimeError(
+                    f"batched rollout still live at t={t_now:.0f} min "
+                    f"(bound {bound:.0f}); unfinished rollouts: "
+                    f"{int(np.sum(~(stop < t_now)))}"
+                )
+        counts["chunks"] = steps_done // chunk_steps
+    with obs.span("batched.result"):
+        return result_of(state, jobs, tables)

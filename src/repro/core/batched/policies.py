@@ -27,6 +27,8 @@ from typing import Optional, Sequence
 
 import numpy as np
 
+# lint: waive[VG001] spans and named scopes only: no semantic change; batched bit-identity suites pin it
+from repro import obs
 from repro.core.batched.tables import DeviceTables
 from repro.core.simulator import DayNightPolicy, RepartitionPolicy, StaticPolicy
 
@@ -83,27 +85,28 @@ def compile_policy(
     same override :class:`SimulationEngine` accepts).  Raises
     :class:`UnsupportedPolicyError` for policies that need simulator state.
     """
-    init_id = policy.initial_config if initial_config is None else initial_config
-    init = _bcast([tables.index_of(int(init_id))], batch)
-    if isinstance(policy, DayNightPolicy):
-        return BatchedPolicy(
-            kind="daynight",
-            initial=init,
-            primary=_bcast([tables.index_of(policy.day_config)], batch),
-            secondary=_bcast([tables.index_of(policy.night_config)], batch),
-            day_start=float(policy.day_start),
-            day_end=float(policy.day_end),
+    with obs.span("batched.compile_policy"):
+        init_id = policy.initial_config if initial_config is None else initial_config
+        init = _bcast([tables.index_of(int(init_id))], batch)
+        if isinstance(policy, DayNightPolicy):
+            return BatchedPolicy(
+                kind="daynight",
+                initial=init,
+                primary=_bcast([tables.index_of(policy.day_config)], batch),
+                secondary=_bcast([tables.index_of(policy.night_config)], batch),
+                day_start=float(policy.day_start),
+                day_end=float(policy.day_end),
+            )
+        # NoMIGPolicy subclasses StaticPolicy, so this covers static + nomig.
+        if isinstance(policy, StaticPolicy):
+            return BatchedPolicy(
+                kind="static", initial=init, primary=init, secondary=init
+            )
+        raise UnsupportedPolicyError(
+            f"policy {type(policy).__name__} needs per-event simulator state; "
+            "the batched backend supports static/nomig/daynight (and the RL env's "
+            "held-target stepping) — run this cell on the oracle backend"
         )
-    # NoMIGPolicy subclasses StaticPolicy, so this covers static + nomig.
-    if isinstance(policy, StaticPolicy):
-        return BatchedPolicy(
-            kind="static", initial=init, primary=init, secondary=init
-        )
-    raise UnsupportedPolicyError(
-        f"policy {type(policy).__name__} needs per-event simulator state; "
-        "the batched backend supports static/nomig/daynight (and the RL env's "
-        "held-target stepping) — run this cell on the oracle backend"
-    )
 
 
 def held_policy(targets: np.ndarray, current: np.ndarray) -> BatchedPolicy:

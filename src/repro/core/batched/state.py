@@ -24,6 +24,8 @@ from typing import Any, Dict, List, Sequence
 
 import numpy as np
 
+# lint: waive[VG001] spans and named scopes only: no semantic change; batched bit-identity suites pin it
+from repro import obs
 from repro.core.jobs import Job
 from repro.core.metrics import SimResult
 
@@ -82,49 +84,50 @@ class BatchedJobs:
         program (the RL trainer's round loop) pass the global maximum so
         every round shares one shape.
         """
-        B = len(job_lists)
-        if B == 0:
-            raise ValueError("empty batch")
-        longest = max((len(js) for js in job_lists), default=0)
-        want = max(longest, int(min_jobs), 1)
-        J = max(pad_multiple, -(-want // pad_multiple) * pad_multiple)
-        K = max_slots + 1
+        with obs.span("batched.pad"):
+            B = len(job_lists)
+            if B == 0:
+                raise ValueError("empty batch")
+            longest = max((len(js) for js in job_lists), default=0)
+            want = max(longest, int(min_jobs), 1)
+            J = max(pad_multiple, -(-want // pad_multiple) * pad_multiple)
+            K = max_slots + 1
 
-        arrival = np.full((B, J), np.inf, dtype=np.float32)
-        deadline = np.full((B, J), np.inf, dtype=np.float32)
-        work = np.zeros((B, J), dtype=np.float32)
-        rates = np.zeros((B, J, K), dtype=np.float32)
-        valid = np.zeros((B, J), dtype=bool)
-        num_jobs = np.zeros((B,), dtype=np.int32)
+            arrival = np.full((B, J), np.inf, dtype=np.float32)
+            deadline = np.full((B, J), np.inf, dtype=np.float32)
+            work = np.zeros((B, J), dtype=np.float32)
+            rates = np.zeros((B, J, K), dtype=np.float32)
+            valid = np.zeros((B, J), dtype=bool)
+            num_jobs = np.zeros((B,), dtype=np.int32)
 
-        for b, jobs in enumerate(job_lists):
-            num_jobs[b] = len(jobs)
-            for j, job in enumerate(jobs):
-                if abs(job.remaining - job.work) > 1e-9:
-                    raise ValueError(
-                        f"rollout {b} job {job.job_id}: partially-run jobs "
-                        "cannot enter a batched rollout"
-                    )
-                arrival[b, j] = job.arrival
-                deadline[b, j] = job.deadline
-                work[b, j] = job.work
-                valid[b, j] = True
-                for k in range(1, K):
-                    rates[b, j, k] = job.rate_on(float(k), mig_enabled)
-        # deadlines are static, so EDF order is too: pre-sorting here turns
-        # the per-step priority selection into a cumsum over a boolean mask
-        # (stable sort keeps the oracle's (deadline, arrival, job_id)
-        # tie-break, since job ids are arrival-ordered)
-        edf_order = np.argsort(deadline, axis=1, kind="stable").astype(np.int32)
-        return cls(
-            arrival=arrival,
-            deadline=deadline,
-            work=work,
-            rate_by_slots=rates,
-            valid=valid,
-            num_jobs=num_jobs,
-            edf_order=edf_order,
-        )
+            for b, jobs in enumerate(job_lists):
+                num_jobs[b] = len(jobs)
+                for j, job in enumerate(jobs):
+                    if abs(job.remaining - job.work) > 1e-9:
+                        raise ValueError(
+                            f"rollout {b} job {job.job_id}: partially-run jobs "
+                            "cannot enter a batched rollout"
+                        )
+                    arrival[b, j] = job.arrival
+                    deadline[b, j] = job.deadline
+                    work[b, j] = job.work
+                    valid[b, j] = True
+                    for k in range(1, K):
+                        rates[b, j, k] = job.rate_on(float(k), mig_enabled)
+            # deadlines are static, so EDF order is too: pre-sorting here turns
+            # the per-step priority selection into a cumsum over a boolean mask
+            # (stable sort keeps the oracle's (deadline, arrival, job_id)
+            # tie-break, since job ids are arrival-ordered)
+            edf_order = np.argsort(deadline, axis=1, kind="stable").astype(np.int32)
+            return cls(
+                arrival=arrival,
+                deadline=deadline,
+                work=work,
+                rate_by_slots=rates,
+                valid=valid,
+                num_jobs=num_jobs,
+                edf_order=edf_order,
+            )
 
 
 @dataclasses.dataclass(frozen=True)
@@ -189,27 +192,28 @@ class BatchedResult:
         ``config_trace`` is empty — like fleet cells, batched cells do not
         record the per-rollout switch trace (documented in docs/BATCHED_SIM.md).
         """
-        out: List[Dict[str, Any]] = []
-        for b, res in enumerate(self.to_sim_results()):
-            hist = {
-                str(k): float(v)
-                for k, v in enumerate(self.util_histogram[b])
-                if v > 0.0
-            }
-            out.append(
-                {
-                    "energy_wh": res.energy_wh,
-                    "avg_tardiness": res.avg_tardiness,
-                    "num_jobs": res.num_jobs,
-                    "total_tardiness": res.total_tardiness,
-                    "preemptions": res.preemptions,
-                    "repartitions": res.repartitions,
-                    "max_tardiness": res.max_tardiness,
-                    "deadline_misses": res.deadline_misses,
-                    "busy_slot_minutes": res.busy_slot_minutes,
-                    "extra": dict(res.extra),
-                    "util_histogram": hist,
-                    "config_trace": [],
+        with obs.span("batched.result"):
+            out: List[Dict[str, Any]] = []
+            for b, res in enumerate(self.to_sim_results()):
+                hist = {
+                    str(k): float(v)
+                    for k, v in enumerate(self.util_histogram[b])
+                    if v > 0.0
                 }
-            )
-        return out
+                out.append(
+                    {
+                        "energy_wh": res.energy_wh,
+                        "avg_tardiness": res.avg_tardiness,
+                        "num_jobs": res.num_jobs,
+                        "total_tardiness": res.total_tardiness,
+                        "preemptions": res.preemptions,
+                        "repartitions": res.repartitions,
+                        "max_tardiness": res.max_tardiness,
+                        "deadline_misses": res.deadline_misses,
+                        "busy_slot_minutes": res.busy_slot_minutes,
+                        "extra": dict(res.extra),
+                        "util_histogram": hist,
+                        "config_trace": [],
+                    }
+                )
+            return out

@@ -24,6 +24,8 @@ from typing import Callable, Iterator, List, Optional, Sequence
 
 import numpy as np
 
+# lint: waive[VG001] spans and named scopes only: no semantic change; batched bit-identity suites pin it
+from repro import obs
 from repro.core.jobs import (
     SUBLINEAR_CURVES,
     Elasticity,
@@ -112,12 +114,16 @@ def sample_poisson_arrivals(
     """
     t = 0.0
     out: List[float] = []
-    while True:
-        t += rng.exponential(1.0 / lam_max)
-        if t >= horizon_min:
-            break
-        if rng.uniform() * lam_max <= rate_fn(t):
-            out.append(t)
+    with obs.span("scenario.arrivals") as counts:
+        candidates = 0
+        while True:
+            t += rng.exponential(1.0 / lam_max)
+            if t >= horizon_min:
+                break
+            candidates += 1
+            if rng.uniform() * lam_max <= rate_fn(t):
+                out.append(t)
+        counts["candidates"], counts["accepted"] = candidates, len(out)
     return out
 
 
@@ -169,29 +175,30 @@ def jobs_from_arrivals(
     bit-identical across the refactor.  ``duration_sampler`` swaps only the
     duration draw (heavy-tailed scenarios).
     """
-    jobs: List[Job] = []
-    for i, t in enumerate(arrivals):
-        is_inf = rng.uniform() < spec.inference_split
-        kind = JobKind.INFERENCE if is_inf else JobKind.TRAINING
-        work = _sample_work(spec, kind, rng, duration_sampler)
-        elast = _sample_elasticity(rng)
-        slack = rng.uniform(spec.slack_lo, spec.slack_hi)
-        dur_fastest = elast.duration(work, 7)
-        deadline = t + slack * dur_fastest
-        jobs.append(
-            Job(
-                job_id=i,
-                kind=kind,
-                arrival=t,
-                work=work,
-                deadline=deadline,
-                elasticity=elast,
-                speedup_no_mig=spec.linear_no_mig_speedup
-                if elast is LINEAR
-                else 1.0,
+    with obs.span("scenario.jobs", jobs=len(arrivals)):
+        jobs: List[Job] = []
+        for i, t in enumerate(arrivals):
+            is_inf = rng.uniform() < spec.inference_split
+            kind = JobKind.INFERENCE if is_inf else JobKind.TRAINING
+            work = _sample_work(spec, kind, rng, duration_sampler)
+            elast = _sample_elasticity(rng)
+            slack = rng.uniform(spec.slack_lo, spec.slack_hi)
+            dur_fastest = elast.duration(work, 7)
+            deadline = t + slack * dur_fastest
+            jobs.append(
+                Job(
+                    job_id=i,
+                    kind=kind,
+                    arrival=t,
+                    work=work,
+                    deadline=deadline,
+                    elasticity=elast,
+                    speedup_no_mig=spec.linear_no_mig_speedup
+                    if elast is LINEAR
+                    else 1.0,
+                )
             )
-        )
-    return jobs
+        return jobs
 
 
 def generate_jobs(

@@ -10,8 +10,7 @@ independent processes.
 The per-cell result dicts come back in the oracle vocabulary
 (:func:`repro.sweep.cells._result_dict` fields) so caching, artifacts and
 aggregation are backend-agnostic; ``config_trace`` is empty for batched
-cells (documented in docs/BATCHED_SIM.md §5) and ``elapsed_s`` divides the
-group's wall time evenly across its cells.
+cells (documented in docs/BATCHED_SIM.md §5).
 
 Unsupported combinations fail loudly *before* any simulation runs:
 schedulers other than EDF-FS, fleet cells, and policies that need
@@ -21,7 +20,6 @@ pointer back to the oracle backend.
 
 from __future__ import annotations
 
-import time
 from typing import Any, Dict, List, Sequence
 
 from repro.sweep.cells import (
@@ -109,8 +107,6 @@ def run_batched_cells(cells: Sequence[Cell]) -> List[Dict[str, Any]]:
     tables = build_tables()
     results: List[Dict[str, Any]] = [{} for _ in cells]
     for idx in groups.values():
-        # lint: waive[DT002] elapsed_s telemetry; stripped before baseline compare
-        t0 = time.perf_counter()
         head = cells[idx[0]]
         job_lists = [cell_jobs(cells[i]) for i in idx]
         jobs = BatchedJobs.from_job_lists(
@@ -126,8 +122,6 @@ def run_batched_cells(cells: Sequence[Cell]) -> List[Dict[str, Any]]:
             repartition_mode=cell_repartition_mode(head),
             dt_min=_resolve_dt(head),
         )
-        elapsed = (time.perf_counter() - t0) / len(idx)  # lint: waive[DT002] telemetry only
         for i, out in zip(idx, res.to_result_dicts(), strict=True):
-            out["elapsed_s"] = elapsed
             results[i] = out
     return results

@@ -27,9 +27,9 @@ from __future__ import annotations
 import dataclasses
 import hashlib
 import json
-import time
 from typing import Any, Callable, Dict, List, Mapping, Optional, Sequence
 
+from repro import obs
 from repro.core.metrics import SimResult, TenantSLOStats
 from repro.core.scenarios import generate_scenario, resolve_scenario_kwargs
 from repro.core.schedulers import make_scheduler
@@ -472,11 +472,12 @@ def cell_hash(cell: Cell, sim_version: str = SIM_VERSION) -> str:
 
 def cell_jobs(cell: Cell) -> List[Any]:
     """Materialize the cell's job stream (scenario cells or raw-spec cells)."""
-    if "scenario" in cell:
-        sc = cell["scenario"]
-        return generate_scenario(sc["name"], seed=cell["seed"], **sc.get("kwargs", {}))
-    spec = WorkloadSpec(**cell["workload"])
-    return generate_jobs(spec, seed=cell["seed"])
+    with obs.span("scenario"):
+        if "scenario" in cell:
+            sc = cell["scenario"]
+            return generate_scenario(sc["name"], seed=cell["seed"], **sc.get("kwargs", {}))
+        spec = WorkloadSpec(**cell["workload"])
+        return generate_jobs(spec, seed=cell["seed"])
 
 
 def _tenants_dict(res: SimResult) -> Dict[str, Dict[str, Any]]:
@@ -494,7 +495,6 @@ def _result_dict(
     res: SimResult,
     util_histogram: Mapping[int, float],
     config_trace: Sequence[Any],
-    t0: float,
 ) -> Dict[str, Any]:
     out = {
         "energy_wh": res.energy_wh,
@@ -510,8 +510,6 @@ def _result_dict(
         # side-channel state some figures aggregate over:
         "util_histogram": {str(k): v for k, v in util_histogram.items()},
         "config_trace": [[t, c] for t, c in config_trace],
-        # lint: waive[DT002] wall telemetry; stripped before baseline compare
-        "elapsed_s": time.perf_counter() - t0,
     }
     # only serving workloads emit tenant stats — batch cells keep the exact
     # historical key set, so pre-serving baselines compare byte-identically
@@ -551,7 +549,6 @@ def _run_fleet_cell(
             # independent instance per device: policies carry run state
             return make_policy(cell["policy"], _cell_policy_kwargs(cell))
 
-    t0 = time.perf_counter()  # lint: waive[DT002] elapsed_s telemetry only
     jobs = cell_jobs(cell)
     fsim = FleetSimulator(spec, mig_enabled=cell["mig_enabled"])
     fres = fsim.run(jobs, policy_factory=per_device_policy)
@@ -560,7 +557,7 @@ def _run_fleet_cell(
     for sim in fsim.sims:
         for k, v in sim.util_histogram.items():
             util[k] = util.get(k, 0.0) + v
-    out = _result_dict(fres.aggregate, util, [], t0)
+    out = _result_dict(fres.aggregate, util, [])
     out["dispatch_counts"] = list(fres.dispatch_counts)
     devices = []
     for d, r in zip(f["devices"], fres.per_device, strict=True):
@@ -615,9 +612,8 @@ def run_cell(
         mig_enabled=cell["mig_enabled"],
         repartition_mode=cell_repartition_mode(cell),
     )
-    t0 = time.perf_counter()  # lint: waive[DT002] elapsed_s telemetry only
     res = sim.run(jobs, policy=policy)
-    return _result_dict(res, sim.util_histogram, sim.config_trace, t0)
+    return _result_dict(res, sim.util_histogram, sim.config_trace)
 
 
 _RESULT_FIELDS = (

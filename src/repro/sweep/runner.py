@@ -53,11 +53,6 @@ class SweepOutcome:
         return len(self.cells)
 
 
-def _strip_volatile(result: Dict[str, Any]) -> Dict[str, Any]:
-    """Drop wall-clock noise so artifacts/cache entries diff cleanly."""
-    return {k: v for k, v in result.items() if k != "elapsed_s"}
-
-
 def _hold_jax_to_cpu() -> None:
     """Pool initializer: a worker's JAX (a ``dqn`` cell's learner) runs on
     the CPU.
@@ -145,8 +140,7 @@ def run_cells(
 
         if progress:
             progress(f"[{name}] {len(batched)} batched cells run in-process")
-        for i, raw in zip(batched, run_batched_cells([cells[i] for i in batched]), strict=True):
-            out = _strip_volatile(raw)
+        for i, out in zip(batched, run_batched_cells([cells[i] for i in batched]), strict=True):
             results[i] = out
             if cache_obj is not None:
                 cache_obj.put(hashes[i], cells[i], out)
@@ -156,7 +150,7 @@ def run_cells(
     if pending:
         if policy_factory is not None or workers <= 1:
             for i in pending:
-                out = _strip_volatile(run_cell(cells[i], policy_factory=policy_factory))
+                out = run_cell(cells[i], policy_factory=policy_factory)
                 results[i] = out
                 if cache_obj is not None:
                     cache_obj.put(hashes[i], cells[i], out)
@@ -175,7 +169,7 @@ def run_cells(
                 for fut in concurrent.futures.as_completed(futs):
                     i = futs[fut]
                     try:
-                        out = _strip_volatile(fut.result())
+                        out = fut.result()
                     except Exception as e:
                         raise RuntimeError(
                             f"sweep cell failed: {canonical_json(cells[i])}"

@@ -352,9 +352,7 @@ def test_legacy_cell_without_mode_key_replays_as_drain():
         k: v for k, v in explicit["policy_kwargs"].items()
         if k != "repartition_mode"
     }
-    out_explicit = {k: v for k, v in run_cell(explicit).items() if k != "elapsed_s"}
-    out_legacy = {k: v for k, v in run_cell(legacy).items() if k != "elapsed_s"}
-    assert out_explicit == out_legacy
+    assert run_cell(explicit) == run_cell(legacy)
 
 
 def test_baseline_partial_beats_drain_for_forecast_on_paper_diurnal():
