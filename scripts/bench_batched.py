@@ -123,8 +123,9 @@ def measure_point(
     from repro.core.batched import DEFAULT_CHUNK_STEPS
     from repro.core.batched.backend import device_constants, init_state, run_steps
 
+    layout = jobs.in_edf_order()
     run_steps(
-        init_state(jobs, policy.initial), jobs, policy,
+        init_state(layout, policy.initial), layout, policy,
         device_constants(tables, "partial"),
         t0_min=0.0, n_steps=DEFAULT_CHUNK_STEPS, dt_min=dt_min,
     )

@@ -36,7 +36,7 @@ from typing import Any, Dict, NamedTuple, Optional, Tuple
 
 import numpy as np
 
-# lint: waive[VG001] spans and named scopes only: no semantic change; batched bit-identity suites pin it
+# lint: waive[VG001] EDF job layout only: selection, tie-breaks and counts unchanged; the oracle-agreement suites pin the semantics
 from repro import obs
 from repro.core.batched.policies import BatchedPolicy
 from repro.core.batched.state import BatchedJobs, BatchedResult
@@ -188,14 +188,15 @@ def make_step_fn(kind: str, dt: float, penalty: float,
         STEP_PHASES
     )
 
-    def step_one(carry, t, arrival, deadline, rates, valid, dorder,
+    def step_one(carry, t, arrival, deadline, rates, valid,
                  primary, secondary,
                  slice_slots, slice_rank, num_slices, o2n, watts):
-        # one rollout, one step.  All per-job state is (J,); everything about
-        # the <= S running jobs lives in (S,) lanes keyed by slice index
-        # (``slice_job``), so the only O(J) work per step is a handful of
-        # fused elementwise ops plus one cumsum — no sorts (EDF order is
-        # static and pre-computed in ``dorder``).
+        # one rollout, one step.  All per-job state is (J,) in the EDF layout
+        # (job index i is the i-th job by (deadline, id); BatchedJobs.
+        # in_edf_order); everything about the <= S running jobs lives in (S,)
+        # lanes keyed by slice index (``slice_job``), so the only O(J) work per
+        # step is a handful of fused elementwise ops plus one cumsum — no
+        # sorts and no permutation: EDF priority is the index order.
         (remaining, completion, slice_job, cfg, pending, stall_left,
          stop_time, energy, tard, busy_min, pre, rep, hist) = carry
         S = slice_slots.shape[1]
@@ -237,16 +238,15 @@ def make_step_fn(kind: str, dt: float, penalty: float,
 
         # -- 3. EDF-FS reassignment (frozen while repartitioning) -------
         with jax.named_scope(edf_rank):
-            # first 2S in-system jobs in EDF order: permute the in-system mask
-            # by the static deadline order, then find the first 2S set bits with
+            # first 2S in-system jobs in EDF order: the job axis is already in
+            # EDF order, so find the first 2S set bits of the in-system mask with
             # a two-level rank search — per-block popcounts, a short cumsum over
             # blocks, and an intra-block scan only for the <= 2S hit blocks.
             # (A full-J cumsum or an O(J)-update scatter here dominates the
             # whole step on CPU XLA.)
             insys = (arrival <= t + _T_EPS) & (remaining > _W_EPS) & valid
-            m = insys[dorder]
             NB = J // _BLOCK
-            mb = m.reshape(NB, _BLOCK)
+            mb = insys.reshape(NB, _BLOCK)
             bc = jnp.cumsum(jnp.sum(mb, axis=1, dtype=i32))  # (NB,)
             ranks = jnp.arange(1, 2 * S + 1, dtype=i32)
             blk = jnp.searchsorted(bc, ranks)  # first block with cum >= rank
@@ -257,7 +257,7 @@ def make_step_fn(kind: str, dt: float, penalty: float,
             need = (ranks - prev)[:, None]
             off = jnp.argmax(sub & (sc == need), axis=1)
             pos = blkc * _BLOCK + off
-            cand = jnp.where(blk < NB, dorder[pos], J)
+            cand = jnp.where(blk < NB, pos, J)
             ranked = slice_rank[cfg]  # (S,) slice ids fastest-first, -1 padded
             rv = (ranked >= 0) & (cand[:S] < J)
             proposed = jnp.full((S,), -1, i32).at[
@@ -407,19 +407,19 @@ def _chunk_fn(kind: str, dt: float, n_steps: int, penalty: float,
     step_one = make_step_fn(kind, dt, penalty, day_start, day_end)
 
     @jax.jit
-    def run_chunk(state, arrival, deadline, rates, valid, dorder,
+    def run_chunk(state, arrival, deadline, rates, valid,
                   primary, secondary, t0,
                   slice_slots, slice_rank, num_slices, o2n, watts):
         step_b = jax.vmap(
             step_one,
-            in_axes=(0, None, 0, 0, 0, 0, 0, 0, 0,
+            in_axes=(0, None, 0, 0, 0, 0, 0, 0,
                      None, None, None, None, None),
         )
 
         def body(carry, i):
             t = t0 + i.astype(jnp.float32) * jnp.float32(dt)
             return (
-                step_b(carry, t, arrival, deadline, rates, valid, dorder,
+                step_b(carry, t, arrival, deadline, rates, valid,
                        primary, secondary,
                        slice_slots, slice_rank, num_slices, o2n, watts),
                 None,
@@ -446,7 +446,9 @@ def run_steps(
 
     The building block both :func:`simulate_batch` and the RL env share;
     the compiled program is cached per (policy kind, dt, n_steps) so
-    repeated calls with the same shapes are compile-free.
+    repeated calls with the same shapes are compile-free.  ``jobs`` (and
+    ``state``) are in the EDF layout of :meth:`BatchedJobs.in_edf_order`:
+    the step reads EDF priority off the job index.
     """
     import jax
     import jax.numpy as jnp
@@ -460,10 +462,15 @@ def run_steps(
             f"padded job axis {jobs.padded_jobs} must be a multiple of "
             f"{_BLOCK} (use BatchedJobs.from_job_lists, which pads to it)"
         )
+    if not (jobs.deadline[:, 1:] >= jobs.deadline[:, :-1]).all():
+        raise ValueError(
+            "jobs are not in the EDF layout (deadlines must be sorted along "
+            "the job axis); pass BatchedJobs.in_edf_order()"
+        )
     key = (policy.kind, float(dt_min), int(n_steps), float(penalty_min),
            float(policy.day_start), float(policy.day_end))
     host = (jobs.arrival, jobs.deadline, jobs.rate_by_slots, jobs.valid,
-            jobs.edf_order, policy.primary, policy.secondary)
+            policy.primary, policy.secondary)
     nbytes = sum(a.nbytes for a in host if isinstance(a, np.ndarray))
     with obs.span("chunk.upload", bytes=nbytes):
         args = (
@@ -567,15 +574,22 @@ def op_phases(hlo_text: str) -> Dict[str, str]:
 def result_of(
     state: RolloutState, jobs: BatchedJobs, tables: DeviceTables
 ) -> BatchedResult:
-    """Materialize a finished carry into a host-side :class:`BatchedResult`."""
+    """Materialize a finished carry into a host-side :class:`BatchedResult`.
+
+    ``state`` runs on ``jobs.in_edf_order()``; ``jobs`` is the caller's
+    batch, and per-job completions come back in its job order.
+    """
     stop = np.asarray(state.stop_time, dtype=np.float64)
+    completion = np.empty(jobs.arrival.shape, dtype=np.float64)
+    np.put_along_axis(completion, jobs.edf_order,
+                      np.asarray(state.completion, dtype=np.float64), axis=1)
     return BatchedResult(
         energy_wh=np.asarray(state.energy_wh, dtype=np.float64),
         tardiness_integral=np.asarray(state.tardiness_integral, np.float64),
         busy_slot_minutes=np.asarray(state.busy_slot_minutes, np.float64),
         preemptions=np.asarray(state.preemptions, dtype=np.int64),
         repartitions=np.asarray(state.repartitions, dtype=np.int64),
-        completion=np.asarray(state.completion, dtype=np.float64),
+        completion=completion,
         deadline=np.asarray(jobs.deadline, dtype=np.float64),
         valid=np.asarray(jobs.valid),
         num_jobs=np.asarray(jobs.num_jobs, dtype=np.int64),
@@ -625,14 +639,15 @@ def simulate_batch(
     if jobs.rate_by_slots.shape[2] != tables.max_slots + 1:
         raise ValueError("jobs rate table was built for a different device")
     consts = device_constants(tables, repartition_mode)
-    state = init_state(jobs, policy.initial)
+    layout = jobs.in_edf_order()
+    state = init_state(layout, policy.initial)
     bound = _horizon_bound(jobs) if max_minutes is None else float(max_minutes)
 
     steps_done = 0
     with obs.span("batched.simulate") as counts:
         while True:
             state = run_steps(
-                state, jobs, policy, consts,
+                state, layout, policy, consts,
                 t0_min=steps_done * dt_min, n_steps=chunk_steps, dt_min=dt_min,
                 penalty_min=tables.penalty_min,
             )

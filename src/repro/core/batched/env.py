@@ -102,6 +102,9 @@ class BatchedRepartitionEnv:
         self.tables = tables if tables is not None else build_tables()
         self._consts = device_constants(self.tables, repartition_mode)
         self._state = None
+        # the caller's batch, for results(); every per-job array the step and
+        # the observations read is in its EDF layout (in_edf_order)
+        self._batch: Optional[BatchedJobs] = None
         self._jobs: Optional[BatchedJobs] = None
         self._inv_mean_dur: Optional[np.ndarray] = None
         self._t = 0.0
@@ -130,10 +133,11 @@ class BatchedRepartitionEnv:
                 ]
             else:
                 job_lists = [generate_jobs(self.spec, seed=s) for s in seeds]
-        self._jobs = BatchedJobs.from_job_lists(
+        self._batch = BatchedJobs.from_job_lists(
             job_lists, max_slots=self.tables.max_slots,
             mig_enabled=self.mig_enabled,
         )
+        self._jobs = self._batch.in_edf_order()
         B, J = self._jobs.arrival.shape
         # mean-duration feature: duration averaged over the canonical slice
         # sizes at mig=True (Job.mean_duration_all_sizes), linear in the
@@ -144,7 +148,9 @@ class BatchedRepartitionEnv:
                 inv[b, j] = sum(
                     1.0 / job.rate_on(float(k), True) for k in ALL_SLICE_SIZES
                 ) / len(ALL_SLICE_SIZES)
-        self._inv_mean_dur = inv
+        self._inv_mean_dur = np.take_along_axis(
+            inv, self._batch.edf_order, axis=1
+        )
         init_idx = np.full((B,), self.tables.index_of(self.initial_config),
                            dtype=np.int32)
         self._state = init_state(self._jobs, init_idx)
@@ -223,9 +229,9 @@ class BatchedRepartitionEnv:
 
     def results(self) -> List[SimResult]:
         """Per-rollout :class:`SimResult` (meaningful for terminated rollouts)."""
-        if self._state is None or self._jobs is None:
+        if self._state is None or self._batch is None:
             raise RuntimeError("no episode has run")
-        return result_of(self._state, self._jobs, self.tables).to_sim_results()
+        return result_of(self._state, self._batch, self.tables).to_sim_results()
 
     # ------------------------------------------------------------------
     def _obs(self) -> np.ndarray:
@@ -255,7 +261,8 @@ class BatchedRepartitionEnv:
         )
         for b in range(B):
             idx = np.flatnonzero(queued[b])
-            # EDF order; stable sort keeps (arrival, job_id) tie order
+            # EDF order; stable sort keeps (arrival, job_id) tie order (the
+            # EDF layout is already in that order, so the sort keeps it)
             idx = idx[np.argsort(deadline[b, idx], kind="stable")]
             for i in range(self.m):
                 if i < len(idx):

@@ -65,6 +65,34 @@ class BatchedJobs:
         """``J`` — padded job capacity per rollout."""
         return int(self.arrival.shape[1])
 
+    def in_edf_order(self) -> "BatchedJobs":
+        """The EDF layout the scan step runs on: every per-job array
+        permuted by ``edf_order``, so job index ``i`` of a rollout is its
+        ``i``-th job in ``(deadline, id)`` order and ``edf_order`` becomes
+        the identity.
+
+        Deadlines are static, so one host permutation per batch replaces a
+        per-step device gather; :func:`repro.core.batched.backend.result_of`
+        maps completions back to this (caller) order.
+        """
+        with obs.span("batched.edf_layout"):
+            order = self.edf_order
+            B, J = order.shape
+
+            def take(a: np.ndarray) -> np.ndarray:
+                idx = order if a.ndim == 2 else order[:, :, None]
+                return np.take_along_axis(a, idx, axis=1)
+
+            return dataclasses.replace(
+                self,
+                arrival=take(self.arrival),
+                deadline=take(self.deadline),
+                work=take(self.work),
+                rate_by_slots=take(self.rate_by_slots),
+                valid=take(self.valid),
+                edf_order=np.tile(np.arange(J, dtype=np.int32), (B, 1)),
+            )
+
     @classmethod
     def from_job_lists(
         cls,

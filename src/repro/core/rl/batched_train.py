@@ -141,16 +141,17 @@ class BatchedTrainStats:
 
 
 def device_observations(
-    state, arrival, deadline, valid, dorder, inv_mean_dur, config_ids,
+    state, arrival, deadline, valid, inv_mean_dur, config_ids,
     t, m: int = M_JOBS,
 ):
     """§IV-D-1 features for every rollout, on device: ``(B, 2+2m)`` float32.
 
     Jit-compatible mirror of ``BatchedRepartitionEnv._obs`` (the host
     reference; tests/test_batched_train.py pins the parity): same bin
-    edges, same sentinels, same EDF-stable ordering via the precomputed
-    ``dorder`` permutation.  The only divergence is float32 arithmetic in
-    the bin inputs, which can flip a binned feature on exact bin edges.
+    edges, same sentinels, same EDF-stable ordering, read off the job index
+    of the EDF layout (:meth:`BatchedJobs.in_edf_order`) the per-job
+    arrays are in.  The only divergence is float32 arithmetic in the bin
+    inputs, which can flip a binned feature on exact bin edges.
     """
     import jax
     import jax.numpy as jnp
@@ -170,15 +171,14 @@ def device_observations(
     queued = (
         (arrival <= t + _EPS) & (state.remaining > _EPS) & (~running) & valid
     )
-    # first-m selection in EDF order: permute the queued mask by the static
-    # deadline order, then find the i-th set bit with a per-row searchsorted
-    # over the running count (J if fewer than i jobs are queued)
-    mq = jnp.take_along_axis(queued, dorder, axis=1).astype(i32)
-    cs = jnp.cumsum(mq, axis=1)
+    # first-m selection in EDF order (the job axis's order): find the i-th
+    # set bit of the queued mask with a per-row searchsorted over the
+    # running count (J if fewer than i jobs are queued)
+    cs = jnp.cumsum(queued.astype(i32), axis=1)
     ranks = jnp.arange(1, m + 1, dtype=i32)
     sel = jax.vmap(lambda c: jnp.searchsorted(c, ranks))(cs)  # (B, m)
     has = sel < J
-    jobsel = jnp.take_along_axis(dorder, jnp.clip(sel, 0, J - 1), axis=1)
+    jobsel = jnp.clip(sel, 0, J - 1)
 
     dl = jnp.take_along_axis(deadline, jobsel, axis=1)
     rem = jnp.take_along_axis(state.remaining, jobsel, axis=1)
@@ -297,7 +297,7 @@ def _make_round_fn(
     )
     step_b = jax.vmap(
         step_one,
-        in_axes=(0, None, 0, 0, 0, 0, 0, 0, 0, None, None, None, None, None),
+        in_axes=(0, None, 0, 0, 0, 0, 0, 0, None, None, None, None, None),
     )
     _, td_update = make_td_update(cfg, lr=lr)
 
@@ -318,7 +318,7 @@ def _make_round_fn(
     i32 = jnp.int32
     f32 = jnp.float32
 
-    def dec_step(carry, k, arrival, deadline, rates, valid, dorder, inv_md):
+    def dec_step(carry, k, arrival, deadline, rates, valid, inv_md):
         (env, obs, params, target, opt_state, replay, rings,
          gstep, updates, key) = carry
         rs, ra, rr, rs2, rdone, rg, pos, size = replay
@@ -352,7 +352,7 @@ def _make_round_fn(
         def inner(c, i):
             ti = t + i.astype(f32) * f32(dt)
             return (
-                step_b(c, ti, arrival, deadline, rates, valid, dorder,
+                step_b(c, ti, arrival, deadline, rates, valid,
                        action, action,
                        consts["slice_slots"], consts["slice_rank"],
                        consts["num_slices"], consts["old_to_new"],
@@ -368,7 +368,7 @@ def _make_round_fn(
 
         t_next = t + interval
         obs2 = device_observations(
-            env2, arrival, deadline, valid, dorder, inv_md, cfg_ids, t_next
+            env2, arrival, deadline, valid, inv_md, cfg_ids, t_next
         )
         done_next = env2.stop_time <= t_next + _EPS
 
@@ -450,14 +450,14 @@ def _make_round_fn(
         return carry, (reward, live, loss, eps)
 
     def round_fn(env0, params, target, opt_state, replay, gstep, updates,
-                 key, arrival, deadline, rates, valid, dorder, inv_md):
+                 key, arrival, deadline, rates, valid, inv_md):
         rings = (
             jnp.zeros((B, n, D), f32),
             jnp.zeros((B, n), i32),
             jnp.zeros((B, n), f32),
         )
         obs0 = device_observations(
-            env0, arrival, deadline, valid, dorder, inv_md, cfg_ids,
+            env0, arrival, deadline, valid, inv_md, cfg_ids,
             jnp.float32(0.0),
         )
         carry0 = (env0, obs0, params, target, opt_state, replay, rings,
@@ -465,7 +465,7 @@ def _make_round_fn(
 
         def body(carry, k):
             return dec_step(
-                carry, k, arrival, deadline, rates, valid, dorder, inv_md
+                carry, k, arrival, deadline, rates, valid, inv_md
             )
 
         carry, outs = lax.scan(body, carry0, jnp.arange(H, dtype=i32))
@@ -566,7 +566,8 @@ def train_dqn_batched(
                     1.0 / job.rate_on(float(k), True) for k in ALL_SLICE_SIZES
                 ) / len(ALL_SLICE_SIZES)
         round_jobs.append(jobs)
-        round_inv.append(inv)
+        # in the EDF layout of the round's arrays (below)
+        round_inv.append(np.take_along_axis(inv, jobs.edf_order, axis=1))
 
     round_fn = _make_round_fn(cfg, tcfg, rewards, tables, consts, lr=lr)
 
@@ -599,12 +600,14 @@ def train_dqn_batched(
     )
     for r in range(rounds):
         jobs = round_jobs[r]
-        env0 = shard_rollouts(init_state(jobs, init_idx), devices)
+        # the round runs on the EDF layout; result_of maps back to `jobs`
+        lay = jobs.in_edf_order()
+        env0 = shard_rollouts(init_state(lay, init_idx), devices)
         batch_arrays = shard_rollouts(
             tuple(
                 jnp.asarray(a)
-                for a in (jobs.arrival, jobs.deadline, jobs.rate_by_slots,
-                          jobs.valid, jobs.edf_order, round_inv[r])
+                for a in (lay.arrival, lay.deadline, lay.rate_by_slots,
+                          lay.valid, round_inv[r])
             ),
             devices,
         )

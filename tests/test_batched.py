@@ -202,6 +202,40 @@ def test_batched_jobs_edf_order_stable():
     assert (order[:-1][ties] < order[1:][ties]).all()
 
 
+def test_edf_layout_permutes_every_job_array():
+    """``in_edf_order`` holds the batch in (deadline, id) order along the job
+    axis, with ``edf_order`` the identity; the caller's batch is untouched."""
+    t = build_tables()
+    lists = [generate_scenario("paper-diurnal", seed=s, load_scale=0.2) for s in range(2)]
+    jobs = BatchedJobs.from_job_lists(lists, max_slots=t.max_slots)
+    before = {f: np.copy(getattr(jobs, f)) for f in ("arrival", "deadline", "edf_order")}
+    lay = jobs.in_edf_order()
+    B, J = jobs.arrival.shape
+    assert (lay.edf_order == np.arange(J)).all()
+    for b in range(B):
+        order = jobs.edf_order[b]
+        assert (order != np.arange(J)).any()  # deadlines out of arrival order
+        for f in ("arrival", "deadline", "work", "rate_by_slots", "valid"):
+            assert np.array_equal(getattr(lay, f)[b], getattr(jobs, f)[b][order]), f
+    assert np.array_equal(lay.num_jobs, jobs.num_jobs)
+    assert (lay.deadline[:, 1:] >= lay.deadline[:, :-1]).all()  # +inf padding last
+    for f, v in before.items():
+        assert np.array_equal(getattr(jobs, f), v), f
+
+
+def test_run_steps_refuses_a_batch_not_in_edf_layout():
+    from repro.core.batched.backend import device_constants, init_state, run_steps
+
+    t = build_tables()
+    jobs = BatchedJobs.from_job_lists(
+        [generate_scenario("paper-diurnal", seed=0, load_scale=0.2)], max_slots=t.max_slots
+    )
+    policy = compile_policy(StaticPolicy(3), t, 1)
+    with pytest.raises(ValueError, match="EDF layout"):
+        run_steps(init_state(jobs, policy.initial), jobs, policy,
+                  device_constants(t), t0_min=0.0, n_steps=1)
+
+
 def test_batched_jobs_rejects_partial_and_empty():
     t = build_tables()
     js = generate_scenario("paper-diurnal", seed=0, load_scale=0.05)
@@ -319,6 +353,128 @@ def test_batched_completion_times_and_makespan():
     assert np.isinf(comp[n:]).all()  # padding rows never complete
     assert (comp[:n] >= jobs.arrival[0, :n] - 1e-6).all()
     assert res.makespan_min[0] >= comp[:n].max() - 1e-3
+
+
+def test_batched_per_job_results_in_caller_order():
+    """The scan runs on the EDF layout; ``simulate_batch`` hands per-job
+    completions back in the caller's job order, each within one grid step of
+    the oracle's on average (§4 D2: a job starts up to ``dt`` late)."""
+    from repro.core.batched import DEFAULT_DT_MIN
+
+    tables = build_tables()
+    seeds = range(4)
+    lists = [generate_scenario("paper-diurnal", seed=s, load_scale=0.2) for s in seeds]
+    jobs = BatchedJobs.from_job_lists(lists, max_slots=tables.max_slots)
+    assert all((jobs.edf_order[b] != np.arange(jobs.padded_jobs)).any() for b in seeds)
+    res = simulate_batch(
+        jobs, compile_policy(DayNightPolicy(), tables, len(lists)), tables=tables
+    )
+    assert np.array_equal(res.deadline, jobs.deadline.astype(np.float64))
+    assert np.array_equal(res.valid, jobs.valid)
+    assert np.array_equal(np.isfinite(res.completion), jobs.valid)
+    batched = res.to_sim_results()
+    for s in seeds:
+        fresh = generate_scenario("paper-diurnal", seed=s, load_scale=0.2)
+        oracle = _oracle(fresh, DayNightPolicy())
+        _assert_agreement(batched[s], oracle, label=f"seed{s}")
+        n = len(fresh)
+        comp = res.completion[s, :n]
+        assert (comp >= jobs.arrival[s, :n] - 1e-6).all()
+        gap = np.abs(comp - np.array([j.completion for j in fresh]))
+        assert gap.mean() <= DEFAULT_DT_MIN, (s, gap.mean())
+
+
+#: ``simulate_batch`` on ``_identity_order_batch`` before the EDF layout
+#: (the step permuted its in-system mask by ``edf_order`` every step)
+_IDENTITY_ORDER_RESULT = {
+    "energy_wh": [4193.0732421875, 4383.82421875, 4414.876953125],
+    "tardiness_integral": [1.57916259765625, 14.116744995117188, 16.1956787109375],
+    "busy_slot_minutes": [3704.81005859375, 4129.62890625, 4257.07568359375],
+    "preemptions": [165, 156, 178],
+    "repartitions": [3, 3, 3],
+    "makespan_min": [1740.0, 1740.0, 1740.0],
+    "completion_sum": 957851.085381031,
+}
+
+
+def _identity_order_batch(tables):
+    """Three loaded days whose deadlines follow arrival order."""
+    lists = [
+        [dataclasses.replace(j, deadline=j.arrival + 20.0)
+         for j in generate_scenario("paper-diurnal", seed=s, load_scale=1.0)]
+        for s in range(3)
+    ]
+    return lists, BatchedJobs.from_job_lists(lists, max_slots=tables.max_slots)
+
+
+def test_batched_identity_order_result_unchanged_by_the_layout():
+    """Where ``edf_order`` is the identity the layout is too, and the result
+    is what it was before the layout (float32 rounding aside) and agrees with
+    the oracle under the §4 tolerances."""
+    tables = build_tables()
+    lists, jobs = _identity_order_batch(tables)
+    assert (jobs.edf_order == np.arange(jobs.padded_jobs)).all()
+    res = simulate_batch(jobs, compile_policy(DayNightPolicy(), tables, 3), tables=tables)
+    want = _IDENTITY_ORDER_RESULT
+    for f in ("preemptions", "repartitions"):
+        assert getattr(res, f).tolist() == want[f], f
+    for f in ("energy_wh", "tardiness_integral", "busy_slot_minutes", "makespan_min"):
+        np.testing.assert_allclose(getattr(res, f), want[f], rtol=1e-6, err_msg=f)
+    np.testing.assert_allclose(res.completion[res.valid].sum(), want["completion_sum"],
+                               rtol=1e-6)
+    for b, js in enumerate(lists):
+        _assert_agreement(res.to_sim_result(b), _oracle(js, DayNightPolicy()), f"row{b}")
+
+
+def _gathers(jaxpr):
+    """Every ``gather`` equation of a jaxpr, nested jaxprs included."""
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "gather":
+            yield eqn
+        for p in eqn.params.values():
+            for sub in p if isinstance(p, (tuple, list)) else (p,):
+                inner = getattr(sub, "jaxpr", sub)
+                if hasattr(inner, "eqns"):
+                    yield from _gathers(inner)
+
+
+def _mask_gathers(jaxpr, B, J):
+    """Gathers whose operand is a job-axis boolean mask, ``(B, J)``."""
+    return [e for e in _gathers(jaxpr)
+            if e.invars[0].aval.dtype == np.bool_ and e.invars[0].aval.shape == (B, J)]
+
+
+def test_scan_step_has_no_per_step_mask_permutation():
+    """The vmapped step and the device observations read EDF priority off
+    the job index: no gather permutes a (B, J) in-system or queued mask."""
+    import jax
+    import jax.numpy as jnp
+
+    from repro.core.batched.backend import _chunk_fn, device_constants, init_state
+    from repro.core.rl.batched_train import device_observations
+
+    tables = build_tables()
+    lists = [generate_scenario("paper-diurnal", seed=s, load_scale=0.2) for s in range(2)]
+    jobs = BatchedJobs.from_job_lists(lists, max_slots=tables.max_slots).in_edf_order()
+    B, J = jobs.arrival.shape
+    policy = compile_policy(DayNightPolicy(), tables, B)
+    consts = device_constants(tables)
+    state = init_state(jobs, policy.initial)
+    chunk = _chunk_fn(policy.kind, 0.5, 2, float(tables.penalty_min),
+                      policy.day_start, policy.day_end)
+    step_jaxpr = jax.make_jaxpr(chunk)(
+        state, jobs.arrival, jobs.deadline, jobs.rate_by_slots, jobs.valid,
+        policy.primary, policy.secondary, np.float32(0.0),
+        consts["slice_slots"], consts["slice_rank"], consts["num_slices"],
+        consts["old_to_new"], consts["watts"],
+    )
+    obs_jaxpr = jax.make_jaxpr(device_observations)(
+        state, jobs.arrival, jobs.deadline, jobs.valid, np.ones((B, J), np.float32),
+        jnp.asarray(tables.config_ids), np.float32(30.0),
+    )
+    assert list(_gathers(step_jaxpr.jaxpr))  # the walk reaches the scan body
+    assert _mask_gathers(step_jaxpr.jaxpr, B, J) == []
+    assert _mask_gathers(obs_jaxpr.jaxpr, B, J) == []
 
 
 # ----------------------------------------------------------------------

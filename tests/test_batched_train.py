@@ -54,7 +54,6 @@ def _obs_via_device(env):
             jnp.asarray(env._jobs.arrival, jnp.float32),
             jnp.asarray(env._jobs.deadline, jnp.float32),
             jnp.asarray(env._jobs.valid),
-            jnp.asarray(env._jobs.edf_order),
             jnp.asarray(env._inv_mean_dur, jnp.float32),
             jnp.asarray(env.tables.config_ids),
             jnp.float32(env._t),
@@ -212,8 +211,8 @@ def test_nstep_replay_accounting_one_transition_per_live_step():
         generate_scenario("paper-diurnal", seed=s, load_scale=0.2)
         for s in (1, 2, 3)
     ]
-    jobs = BatchedJobs.from_job_lists(chunks, max_slots=tables.max_slots)
-    inv = np.zeros(jobs.arrival.shape, np.float32)
+    batch = BatchedJobs.from_job_lists(chunks, max_slots=tables.max_slots)
+    inv = np.zeros(batch.arrival.shape, np.float32)
     for b, js in enumerate(chunks):
         for j, job in enumerate(js):
             inv[b, j] = sum(
@@ -228,11 +227,13 @@ def test_nstep_replay_accounting_one_transition_per_live_step():
         jnp.zeros((), jnp.int32), jnp.zeros((), jnp.int32),
     )
     learner = DQNLearner(cfg)
+    jobs = batch.in_edf_order()
+    inv = np.take_along_axis(inv, batch.edf_order, axis=1)
     env0 = init_state(jobs, np.full((3,), tables.index_of(2), np.int32))
     arrays = tuple(
         jnp.asarray(a)
         for a in (jobs.arrival, jobs.deadline, jobs.rate_by_slots,
-                  jobs.valid, jobs.edf_order, inv)
+                  jobs.valid, inv)
     )
     (env, _p, _t, _o, replay, gstep, updates, _k, outs) = round_fn(
         env0, learner.params, learner.target, learner.opt_state, replay,

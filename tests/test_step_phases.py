@@ -52,11 +52,12 @@ def no_scopes(monkeypatch):
 def _chunk_text(jobs, policy, tables) -> str:
     """Compiled HLO text of the chunk program ``simulate_batch`` runs."""
     consts = backend.device_constants(tables)
+    jobs = jobs.in_edf_order()
     state = backend.init_state(jobs, policy.initial)
     fn = backend._chunk_fn(policy.kind, backend.DEFAULT_DT_MIN, backend.DEFAULT_CHUNK_STEPS,
                            float(tables.penalty_min), policy.day_start, policy.day_end)
     args = (state, jobs.arrival, jobs.deadline, jobs.rate_by_slots, jobs.valid,
-            jobs.edf_order, policy.primary, policy.secondary, np.float32(0.0),
+            policy.primary, policy.secondary, np.float32(0.0),
             consts["slice_slots"], consts["slice_rank"], consts["num_slices"],
             consts["old_to_new"], consts["watts"])
     return fn.lower(*args).compile().as_text()

@@ -89,7 +89,6 @@ def _jobs(B, J, K, sharding):
         jax.ShapeDtypeStruct((B, J), f32, sharding=sharding),  # deadline
         jax.ShapeDtypeStruct((B, J, K), f32, sharding=sharding),  # rates
         jax.ShapeDtypeStruct((B, J), jnp.bool_, sharding=sharding),  # valid
-        jax.ShapeDtypeStruct((B, J), jnp.int32, sharding=sharding),  # edf order
     )
 
 
