@@ -4,7 +4,11 @@ docs/BATCHED_SIM.md §4 fixes how far one batched rollout's aggregates may
 drift from the oracle's run of the same jobs.  This module is that table and
 its comparison, read by ``tests/test_batched.py`` and ``chip_smoke.py`` alike.
 Tightening a tolerance requires re-measuring the calibration matrix;
-loosening one requires naming the new divergence source in §4.
+loosening one requires naming the new divergence source in §4.  A fleet
+rollout (``simulate_batch(..., devices=D)``) is held to the same table
+against :class:`repro.fleet.FleetSimulator`'s aggregate, but for its
+preemptions: dispatch on the grid (§4 D6) places a step's arrivals
+together, where the oracle re-places at each, so D4's undercount grows.
 """
 
 from __future__ import annotations
@@ -17,6 +21,7 @@ __all__ = [
     "BUSY_ATOL_MIN",
     "BUSY_RTOL",
     "ENERGY_RTOL",
+    "FLEET_PREEMPTIONS_RTOL",
     "PREEMPTIONS_FLOOR",
     "PREEMPTIONS_RTOL",
     "TARDINESS_ATOL_MIN",
@@ -34,14 +39,17 @@ BUSY_RTOL = 0.025
 BUSY_ATOL_MIN = 1.0  # slot-minutes: near-idle days compare absolutely
 PREEMPTIONS_RTOL = 0.4  # relative to max(oracle, PREEMPTIONS_FLOOR)
 PREEMPTIONS_FLOOR = 10.0
+# measured at dt=0.5 on fleets of 3 and 8 GPUs, least-loaded, full days (§4, D6)
+FLEET_PREEMPTIONS_RTOL = 0.5
 
 
-def agreement_failures(batched: SimResult, oracle: SimResult) -> List[str]:
+def agreement_failures(batched: SimResult, oracle: SimResult, devices: int = 1) -> List[str]:
     """The §4 columns on which one batched rollout misses its oracle run.
 
     An empty list means the two agree.  Job and repartition counts must be
     exact; energy, tardiness, busy-slot minutes and preemptions are held to
-    the tolerances above.
+    the tolerances above, a fleet's preemptions (``devices > 1``) to
+    :data:`FLEET_PREEMPTIONS_RTOL`.
     """
     b, o = batched, oracle
     out: List[str] = []
@@ -62,7 +70,8 @@ def agreement_failures(batched: SimResult, oracle: SimResult) -> List[str]:
         out.append(
             f"busy_slot_minutes {b.busy_slot_minutes} vs {o.busy_slot_minutes}"
         )
-    if abs(b.preemptions - o.preemptions) > PREEMPTIONS_RTOL * max(
+    pre_rtol = FLEET_PREEMPTIONS_RTOL if devices > 1 else PREEMPTIONS_RTOL
+    if abs(b.preemptions - o.preemptions) > pre_rtol * max(
         o.preemptions, PREEMPTIONS_FLOOR
     ):
         out.append(f"preemptions {b.preemptions} vs {o.preemptions}")

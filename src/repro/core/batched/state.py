@@ -20,11 +20,11 @@ baselines) is backend-agnostic.
 from __future__ import annotations
 
 import dataclasses
-from typing import Any, Dict, List, Sequence
+from typing import Any, Dict, List, Optional, Sequence
 
 import numpy as np
 
-# lint: waive[VG001] spans and named scopes only: no semantic change; batched bit-identity suites pin it
+# lint: waive[VG001] a fleet's arrival order and GPU of each job beside the one-GPU containers; bit-identity suites pin the one-GPU path
 from repro import obs
 from repro.core.jobs import Job
 from repro.core.metrics import SimResult
@@ -64,6 +64,20 @@ class BatchedJobs:
     def padded_jobs(self) -> int:
         """``J`` — padded job capacity per rollout."""
         return int(self.arrival.shape[1])
+
+    def by_arrival(self) -> np.ndarray:
+        """``(B, J)`` int32: the EDF-layout index (:meth:`in_edf_order`) of
+        each rollout's ``r``-th job in (arrival, id) order, padding last.
+
+        A fleet's scan routes its arrivals in this order (docs/BATCHED_SIM.md
+        §3); one host permutation per batch, like the layout itself.
+        """
+        B, J = self.edf_order.shape
+        pos = np.empty_like(self.edf_order)
+        np.put_along_axis(pos, self.edf_order,
+                          np.broadcast_to(np.arange(J, dtype=np.int32), (B, J)), axis=1)
+        order = np.argsort(self.arrival, axis=1, kind="stable")
+        return np.take_along_axis(pos, order, axis=1)
 
     def in_edf_order(self) -> "BatchedJobs":
         """The EDF layout the scan step runs on: every per-job array
@@ -164,7 +178,10 @@ class BatchedResult:
 
     Mirrors the oracle's :class:`SimResult` fields plus the side channels the
     sweep layer records (utilization histogram); ``completion`` keeps the
-    exact per-job finish times (``+inf`` for padding rows).
+    exact per-job finish times (``+inf`` for padding rows).  A fleet's
+    aggregates are sums over its ``devices`` GPUs (``util_histogram`` in
+    GPU-minutes), as :func:`repro.fleet.aggregate_sim_results` sums them,
+    and ``device`` holds the GPU each job was routed to.
     """
 
     energy_wh: np.ndarray  # (B,) float64
@@ -178,11 +195,21 @@ class BatchedResult:
     num_jobs: np.ndarray  # (B,) int64
     makespan_min: np.ndarray  # (B,) float64
     util_histogram: np.ndarray  # (B, K) float64 minutes at each busy level
+    device: Optional[np.ndarray] = None  # (B, J) int32 a fleet's GPU of each job
+    devices: int = 1
 
     @property
     def batch(self) -> int:
         """``B`` — rollout count."""
         return int(self.energy_wh.shape[0])
+
+    def dispatch_counts(self) -> np.ndarray:
+        """``(B, devices)`` int64: jobs routed to each GPU of each rollout."""
+        if self.device is None:
+            return self.num_jobs[:, None].astype(np.int64)
+        dev = np.where(self.valid, self.device, self.devices)
+        return np.stack([np.bincount(row, minlength=self.devices + 1)[: self.devices]
+                         for row in dev]).astype(np.int64)
 
     def _tardiness(self, b: int) -> np.ndarray:
         mask = self.valid[b]
@@ -219,9 +246,11 @@ class BatchedResult:
 
         ``config_trace`` is empty — like fleet cells, batched cells do not
         record the per-rollout switch trace (documented in docs/BATCHED_SIM.md).
+        A fleet's dicts add ``dispatch_counts``, as the oracle's fleet cells.
         """
         with obs.span("batched.result"):
             out: List[Dict[str, Any]] = []
+            routed = self.dispatch_counts() if self.devices > 1 else None
             for b, res in enumerate(self.to_sim_results()):
                 hist = {
                     str(k): float(v)
@@ -244,4 +273,6 @@ class BatchedResult:
                         "config_trace": [],
                     }
                 )
+                if routed is not None:
+                    out[-1]["dispatch_counts"] = routed[b].tolist()
             return out

@@ -12,8 +12,12 @@ The per-cell result dicts come back in the oracle vocabulary
 aggregation are backend-agnostic; ``config_trace`` is empty for batched
 cells (documented in docs/BATCHED_SIM.md §5).
 
+Fleet cells run as one batch of fleets: homogeneous fleets behind
+``least-loaded`` or ``round-robin`` dispatch (:func:`repro.sweep.cells.
+batched_fleet_refusal`), on the scan's device axis.
+
 Unsupported combinations fail loudly *before* any simulation runs:
-schedulers other than EDF-FS, fleet cells, and policies that need
+schedulers other than EDF-FS, other fleets, and policies that need
 per-event simulator state all raise :class:`UnsupportedPolicyError` with a
 pointer back to the oracle backend.
 """
@@ -24,6 +28,7 @@ from typing import Any, Dict, List, Sequence
 
 from repro.sweep.cells import (
     Cell,
+    batched_fleet_refusal,
     canonical_json,
     cell_jobs,
     cell_repartition_mode,
@@ -62,10 +67,9 @@ def validate_batched_cell(cell: Cell) -> None:
     from repro.core.batched import UnsupportedPolicyError
 
     if "fleet" in cell:
-        raise UnsupportedPolicyError(
-            "fleet cells need the co-advanced dispatcher loop; "
-            "run them on the oracle backend"
-        )
+        refusal = batched_fleet_refusal(cell["fleet"])
+        if refusal:
+            raise UnsupportedPolicyError(refusal)
     if cell.get("scheduler") != "EDF-FS":
         raise UnsupportedPolicyError(
             f"batched backend implements only EDF-FS "
@@ -89,7 +93,9 @@ def run_batched_cells(cells: Sequence[Cell]) -> List[Dict[str, Any]]:
 
     Each group compiles its policy once (:func:`compile_policy` on a fresh
     registry instance, so batched cells honour exactly the defaults oracle
-    cells get) and runs one vectorized rollout over its seeds.
+    cells get) and runs one vectorized rollout over its seeds; a fleet
+    group runs each seed on its ``D`` devices, every device the policy's
+    own instance of the same compiled targets.
     """
     from repro.core.batched import (
         BatchedJobs,
@@ -104,10 +110,19 @@ def run_batched_cells(cells: Sequence[Cell]) -> List[Dict[str, Any]]:
         validate_batched_cell(cell)
         groups.setdefault(batched_group_key(cell), []).append(i)
 
-    tables = build_tables()
+    one_gpu = build_tables()
     results: List[Dict[str, Any]] = [{} for _ in cells]
     for idx in groups.values():
         head = cells[idx[0]]
+        fleet = head.get("fleet")
+        if fleet:
+            from repro.fleet.devices import device_profile
+
+            prof = device_profile(fleet["devices"][0]["profile"])
+            tables = build_tables(prof.configs, prof.power)
+            devices, dispatcher = len(fleet["devices"]), fleet["dispatcher"]
+        else:
+            tables, devices, dispatcher = one_gpu, 1, "least-loaded"
         job_lists = [cell_jobs(cells[i]) for i in idx]
         jobs = BatchedJobs.from_job_lists(
             job_lists, max_slots=tables.max_slots,
@@ -120,7 +135,7 @@ def run_batched_cells(cells: Sequence[Cell]) -> List[Dict[str, Any]]:
         res = simulate_batch(
             jobs, policy, tables=tables,
             repartition_mode=cell_repartition_mode(head),
-            dt_min=_resolve_dt(head),
+            dt_min=_resolve_dt(head), devices=devices, dispatcher=dispatcher,
         )
         for i, out in zip(idx, res.to_result_dicts(), strict=True):
             results[i] = out

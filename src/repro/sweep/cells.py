@@ -47,6 +47,7 @@ from repro.core.workload import WorkloadSpec, generate_jobs
 __all__ = [
     "POLICIES",
     "CellSpec",
+    "batched_fleet_refusal",
     "canonical_json",
     "cell_hash",
     "cell_jobs",
@@ -61,6 +62,37 @@ __all__ = [
 ]
 
 Cell = Dict[str, Any]
+
+def batched_fleet_refusal(fleet: Mapping[str, Any]) -> Optional[str]:
+    """Why the batched backend cannot run a cell's ``fleet``, or None.
+
+    It runs fleets of one device profile on the A100 partition table, every
+    device as the fleet default, behind online ``least-loaded`` or
+    ``round-robin`` dispatch (docs/BATCHED_SIM.md §2-§3).  Mixed tables and
+    the dispatchers that read more than the backlog need the co-advanced
+    dispatcher loop of the oracle.
+    """
+    from repro.core.batched.backend import DISPATCHERS
+    from repro.core.slices import MIG_CONFIGS
+    from repro.fleet.devices import DEVICE_PROFILES
+
+    devices = list(fleet.get("devices") or [])
+    profiles = sorted({d.get("profile") for d in devices})
+    if fleet.get("dispatcher") not in DISPATCHERS:
+        why = f"dispatcher {fleet.get('dispatcher')!r}; it runs {' and '.join(DISPATCHERS)} only"
+    elif len(profiles) != 1:
+        why = f"a fleet of mixed profiles {profiles}; it runs one profile per fleet"
+    elif profiles[0] not in DEVICE_PROFILES or (
+            dict(DEVICE_PROFILES[profiles[0]].configs) != dict(MIG_CONFIGS)):
+        why = f"profile {profiles[0]!r}; it runs profiles on the A100 partition table"
+    elif any(set(d) - {"profile"} for d in devices):
+        why = "per-device scheduler or initial_config overrides"
+    elif fleet.get("info", "online") != "online":
+        why = f"dispatch_info {fleet.get('info')!r}; it dispatches online"
+    else:
+        return None
+    return (f"the batched backend cannot run this fleet cell ({why}); the other "
+            "fleets need the co-advanced dispatcher loop: run them on the oracle backend")
 
 
 def cell_repartition_mode(cell: Cell) -> str:
@@ -306,8 +338,6 @@ class CellSpec:
             raise ValueError("fleet cells require a dispatcher")
         if not is_fleet and self.dispatcher is not None:
             raise ValueError("dispatcher only applies to fleet cells")
-        if is_fleet and self.backend != "oracle":
-            raise ValueError("fleet cells only run on the oracle backend")
         cell = _base_cell(
             experiment=self.experiment,
             group=self.group,
@@ -333,6 +363,11 @@ class CellSpec:
                 "dispatcher": self.dispatcher,
                 "info": self.dispatch_info,
             }
+            refusal = self.backend == "batched" and batched_fleet_refusal(cell["fleet"])
+            if refusal:
+                from repro.core.batched import UnsupportedPolicyError
+
+                raise UnsupportedPolicyError(refusal)
         return cell
 
 
@@ -422,6 +457,8 @@ def make_fleet_cell(
     mig_enabled: bool = True,
     dispatch_info: str = "online",
     repartition_mode: str = "partial",
+    backend: str = "oracle",
+    backend_kwargs: Optional[Mapping[str, Any]] = None,
 ) -> Cell:
     """A fleet cell: N devices (by profile name) behind a dispatcher.
 
@@ -432,6 +469,8 @@ def make_fleet_cell(
     observes — ``"online"`` (real co-advanced engine state, the default) or
     ``"fluid"`` (the legacy backlog-estimate pre-split); the resolved value
     always enters the cell so the content hash captures it.
+    ``backend="batched"`` runs the fleets :func:`batched_fleet_refusal`
+    accepts on the batched scan and refuses the rest.
     """
     return CellSpec(
         experiment=experiment,
@@ -447,6 +486,8 @@ def make_fleet_cell(
         fleet_profiles=tuple(profiles),
         dispatcher=dispatcher,
         dispatch_info=dispatch_info,
+        backend=backend,
+        backend_kwargs=backend_kwargs,
     ).to_cell()
 
 

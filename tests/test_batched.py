@@ -57,9 +57,9 @@ def _oracle(jobs, policy, repartition_mode="partial"):
     return engine.result()
 
 
-def _assert_agreement(b, o, label=""):
+def _assert_agreement(b, o, label="", devices=1):
     """One rollout's batched aggregates vs its oracle run (BATCHED_SIM.md §4)."""
-    failures = agreement_failures(b, o)
+    failures = agreement_failures(b, o, devices)
     assert not failures, f"{label}: {failures}"
 
 
@@ -94,6 +94,22 @@ def test_agreement_failures_names_each_column(field, value, column):
         assert failures == []
     else:
         assert len(failures) == 1 and failures[0].startswith(column), failures
+
+
+def test_fleet_preemptions_have_their_own_tolerance():
+    """A fleet's preemptions: 50% (dispatch on the grid, §4 D6), not 40%."""
+    from repro.core.metrics import SimResult
+
+    oracle = SimResult(
+        energy_wh=1000.0, avg_tardiness=2.0, num_jobs=100, total_tardiness=200.0,
+        preemptions=50, repartitions=4, max_tardiness=9.0, deadline_misses=20,
+        busy_slot_minutes=400.0,
+    )
+    batched = dataclasses.replace(oracle, preemptions=50 - 24)
+    assert agreement_failures(batched, oracle)[0].startswith("preemptions")
+    assert agreement_failures(batched, oracle, devices=8) == []
+    batched = dataclasses.replace(oracle, preemptions=50 - 26)
+    assert agreement_failures(batched, oracle, devices=8)[0].startswith("preemptions")
 
 
 def test_agreement_floors_for_light_days():
@@ -511,3 +527,99 @@ def test_batched_env_rejects_bad_cadence_and_scheduler():
         BatchedRepartitionEnv(scheduler_name="EDF-SS")
     with pytest.raises(ValueError, match="multiple"):
         BatchedRepartitionEnv(decision_interval_min=0.7)
+
+
+# ----------------------------------------------------------------------
+# fleets: a device axis in the scan, against the oracle's FleetSimulator
+
+
+def _fleet_oracle(jobs, devices, dispatcher):
+    from repro.fleet import FleetSimulator, FleetSpec
+
+    fleet = FleetSimulator(FleetSpec.of(["a100-250w"] * devices, dispatcher=dispatcher,
+                                        scheduler="EDF-FS"))
+    return fleet.run(jobs, lambda i, prof: DayNightPolicy())
+
+
+@pytest.mark.parametrize("dispatcher", ["least-loaded", "round-robin"])
+def test_batched_fleet_matches_fleet_simulator(dispatcher):
+    """B=4 rollouts of a 3-GPU fleet agree with the oracle's online fleet
+    within the §4 tolerances (the dispatch grid is divergence D6)."""
+    tables = build_tables()
+    lists = [generate_scenario("paper-diurnal", seed=s, load_scale=3.0, horizon_min=180.0)
+             for s in range(4)]
+    jobs = BatchedJobs.from_job_lists(lists, max_slots=tables.max_slots)
+    res = simulate_batch(jobs, compile_policy(DayNightPolicy(), tables, len(lists)),
+                         tables=tables, devices=3, dispatcher=dispatcher)
+    routed = res.dispatch_counts()
+    for b, js in enumerate(lists):
+        oracle = _fleet_oracle(js, 3, dispatcher)
+        _assert_agreement(res.to_sim_result(b), oracle.aggregate, label=f"{dispatcher}/{b}",
+                          devices=3)
+        assert routed[b].sum() == len(js) and (routed[b] > 0).all()
+        if dispatcher == "round-robin":  # the arrival rank alone decides
+            assert routed[b].tolist() == oracle.dispatch_counts
+
+
+def _hand_made_day():
+    """Three arrivals in the first step, two in the step at 10.5 min: linear
+    jobs on the night configuration's 4- and 3-slot slices."""
+    from repro.core.jobs import LINEAR, Job, JobKind
+
+    spec = [(0.1, 70.0), (0.2, 7.0), (0.3, 1.0), (10.2, 2.0), (10.3, 1.0)]
+    return [Job(job_id=i, kind=JobKind.TRAINING, arrival=a, work=w, deadline=a + 60.0,
+                elasticity=LINEAR) for i, (a, w) in enumerate(spec)]
+
+
+@pytest.mark.parametrize("dispatcher,want", [
+    # least-loaded: job 0 takes GPU 0 (a tie, to the lower index); jobs 1
+    # and 2 see job 0's 70 on GPU 0 and job 1's 7, routed in the same step,
+    # on GPU 1; at 10.5 min GPU 0 still holds 30 of job 0, GPU 1 nothing
+    ("least-loaded", [0, 1, 1, 1, 1]),
+    ("round-robin", [0, 1, 0, 1, 0]),  # arrival rank mod 2
+])
+def test_fleet_dispatch_order_on_a_hand_made_day(dispatcher, want):
+    tables = build_tables()
+    day = _hand_made_day()
+    jobs = BatchedJobs.from_job_lists([day, day], max_slots=tables.max_slots)
+    res = simulate_batch(jobs, compile_policy(DayNightPolicy(), tables, 2), tables=tables,
+                         devices=2, dispatcher=dispatcher)
+    for b in range(2):
+        assert res.device[b, :5].tolist() == want
+    oracle = _fleet_oracle(_hand_made_day(), 2, dispatcher)
+    assert oracle.dispatch_counts == np.bincount(want, minlength=2).tolist()
+
+
+def test_by_arrival_indexes_the_edf_layout_in_arrival_order():
+    t = build_tables()
+    lists = [generate_scenario("paper-diurnal", seed=s, load_scale=0.3) for s in range(2)]
+    jobs = BatchedJobs.from_job_lists(lists, max_slots=t.max_slots)
+    lay, order = jobs.in_edf_order(), jobs.by_arrival()
+    for b, js in enumerate(lists):
+        n = len(js)
+        assert np.array_equal(lay.arrival[b][order[b, :n]], jobs.arrival[b, :n])
+        assert sorted(order[b].tolist()) == list(range(jobs.padded_jobs))
+
+
+def test_fleet_spans_count_devices_and_jobs_routed(tmp_path):
+    """While a profiler session runs, ``batched.simulate`` counts the fleet's
+    GPUs and ``batched.result`` the most and fewest jobs any GPU was routed."""
+    import jax
+
+    from repro import obs
+
+    tables = build_tables()
+    day = _hand_made_day()
+    jobs = BatchedJobs.from_job_lists([day, day[:2]], max_slots=tables.max_slots)
+    obs.clear()
+    jax.profiler.start_trace(str(tmp_path / "trace"))
+    try:
+        simulate_batch(jobs, compile_policy(DayNightPolicy(), tables, 2), tables=tables,
+                       devices=2, dispatcher="least-loaded")
+    finally:
+        jax.profiler.stop_trace()
+    spans = {s.name: s for s in obs.records() if s is not None}
+    obs.clear()
+    assert spans["batched.simulate"].counts["devices"] == 2
+    # row 0 routes [0, 1, 1, 1, 1], row 1 its first two jobs [0, 1]
+    assert spans["batched.result"].counts == {"jobs_routed_max": 4, "jobs_routed_min": 1}

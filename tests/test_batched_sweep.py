@@ -103,6 +103,62 @@ def test_validate_rejects_wrong_scheduler_and_fleet():
         run_cell(fleet)
 
 
+def _fleet(profiles=("a100-250w",) * 2, dispatcher="least-loaded", seed=0,
+           backend="batched", **kw):
+    return make_fleet_cell(
+        experiment="t", group="g", profiles=list(profiles), dispatcher=dispatcher,
+        scheduler="EDF-FS", scenario="paper-diurnal", seed=seed,
+        scenario_kwargs={"load_scale": 0.6}, policy="daynight", backend=backend, **kw,
+    )
+
+
+@pytest.mark.parametrize("profiles,dispatcher,info,why", [
+    (("a100-250w", "a30-165w"), "least-loaded", "online", "mixed profiles"),
+    (("a30-165w",) * 2, "least-loaded", "online", "A100 partition table"),
+    (("a100-250w",) * 2, "energy-greedy", "online", "dispatcher 'energy-greedy'"),
+    (("a100-250w",) * 2, "state-aware", "online", "dispatcher 'state-aware'"),
+    (("a100-250w",) * 2, "least-loaded", "fluid", "dispatches online"),
+])
+def test_unsupported_fleets_are_refused_on_both_paths(profiles, dispatcher, info, why):
+    """The refusal names what the batched backend lacks and points to the
+    oracle, both when the cell is built and when a hand-made one is run."""
+    with pytest.raises(UnsupportedPolicyError, match=why) as built:
+        _fleet(profiles, dispatcher, dispatch_info=info)
+    assert "oracle backend" in str(built.value)
+    cell = _fleet(profiles, dispatcher, backend="oracle", dispatch_info=info)
+    cell["backend"], cell["backend_kwargs"] = "batched", {"dt_min": 0.5}
+    with pytest.raises(UnsupportedPolicyError, match=why):
+        run_batched_cells([cell])
+    cell = _fleet()
+    cell["fleet"]["devices"][1]["initial_config"] = 3
+    with pytest.raises(UnsupportedPolicyError, match="overrides"):
+        validate_batched_cell(cell)
+
+
+@pytest.mark.parametrize("dispatcher", ["least-loaded", "round-robin"])
+def test_supported_fleets_run_batched_through_the_runner(dispatcher):
+    """Homogeneous A100 fleets run on the scan, one batch per physics."""
+    cells = [_fleet(dispatcher=dispatcher, seed=s) for s in range(3)]
+    assert is_batched_cell(cells[0])
+    assert batched_group_key(cells[0]) == batched_group_key(cells[2])
+    out = run_batched_cells(cells)
+    oracle = run_cell(_fleet(dispatcher=dispatcher, backend="oracle"))
+    assert set(out[0]) == set(oracle) - {"devices"}
+    for r in out:
+        assert sum(r["dispatch_counts"]) == r["num_jobs"] and len(r["dispatch_counts"]) == 2
+    assert out[0]["num_jobs"] == oracle["num_jobs"]
+    assert out[0]["repartitions"] == oracle["repartitions"]
+    assert out[0]["energy_wh"] == pytest.approx(oracle["energy_wh"], rel=0.03)
+
+
+def test_one_device_fleet_is_the_single_gpu_run_bit_for_bit():
+    fleet = run_batched_cells([_fleet(profiles=["a100-250w"], seed=s) for s in (4, 5)])
+    single = run_batched_cells([_cell(seed=s, policy="daynight") | {
+        "scenario": _fleet(seed=s)["scenario"]} for s in (4, 5)])
+    for f, g in zip(fleet, single, strict=True):
+        assert f == g
+
+
 def test_stateful_policy_rejected_with_guidance():
     with pytest.raises(UnsupportedPolicyError, match="oracle backend|oracle"):
         run_batched_cells([_cell(policy="heuristic")])

@@ -5,7 +5,8 @@
 chunk programs run while a profiler session was on.  On the CPU at a tiny
 size: the scopes leave the program and its results as they were, and the
 map holds where JAX's persistent cache served an executable built without
-them.
+them.  One GPU has no ``dispatch`` op (every job is on device 0); a fleet's
+chunk has every phase.
 """
 
 from __future__ import annotations
@@ -49,17 +50,23 @@ def no_scopes(monkeypatch):
     _rebuild_step()
 
 
-def _chunk_text(jobs, policy, tables) -> str:
+#: the phases a one-GPU chunk has: ``dispatch`` folds to device 0 and leaves no op
+ONE_GPU_PHASES = set(STEP_PHASES) - {"dispatch"}
+
+
+def _chunk_text(jobs, policy, tables, devices=1) -> str:
     """Compiled HLO text of the chunk program ``simulate_batch`` runs."""
     consts = backend.device_constants(tables)
+    by_arrival = (jobs.by_arrival(),) if devices > 1 else ()
     jobs = jobs.in_edf_order()
-    state = backend.init_state(jobs, policy.initial)
+    state = backend.init_state(jobs, policy.initial, devices)
+    fleet = (devices, "least-loaded") if devices > 1 else ()
     fn = backend._chunk_fn(policy.kind, backend.DEFAULT_DT_MIN, backend.DEFAULT_CHUNK_STEPS,
-                           float(tables.penalty_min), policy.day_start, policy.day_end)
+                           float(tables.penalty_min), policy.day_start, policy.day_end, *fleet)
     args = (state, jobs.arrival, jobs.deadline, jobs.rate_by_slots, jobs.valid,
             policy.primary, policy.secondary, np.float32(0.0),
             consts["slice_slots"], consts["slice_rank"], consts["num_slices"],
-            consts["old_to_new"], consts["watts"])
+            consts["old_to_new"], consts["watts"], *by_arrival)
     return fn.lower(*args).compile().as_text()
 
 
@@ -92,7 +99,7 @@ def test_scopes_change_op_metadata_only(no_persistent_cache):
         bare = simulate_batch(jobs, policy, tables=tables)
     _rebuild_step()
     assert set(op_phases(bare_text).values()) == {""}
-    assert set(op_phases(scoped_text).values()) >= set(STEP_PHASES)
+    assert set(op_phases(scoped_text).values()) == ONE_GPU_PHASES | {""}
     assert _without_metadata(scoped_text) == _without_metadata(bare_text)
     for field in ("energy_wh", "tardiness_integral", "busy_slot_minutes", "preemptions",
                   "repartitions", "completion", "makespan_min", "util_histogram"):
@@ -141,11 +148,35 @@ def test_op_scopes_hold_over_a_cache_filled_without_scopes(persistent_cache, no_
     assert set(op_phases(ran).values()) == {""}
 
     scopes = chunk_op_scopes()
-    assert set(scopes.values()) >= set(STEP_PHASES)
+    assert set(scopes.values()) >= ONE_GPU_PHASES
     assert set(scopes) == set(op_phases(ran))
     assert jax.config.jax_enable_compilation_cache  # the setting is back
     assert len(backend._PROFILED_CHUNKS) == 1
     obs.clear()
+
+
+#: ops that move or hold values and compute nothing: a step's are shared
+#: across its phases, so they carry no phase of their own
+_STRUCTURAL = re.compile(r" (parameter|get-tuple-element|tuple|constant|copy|bitcast)\("
+                         r"|calls=%?wrapped_broadcast")
+
+
+@pytest.mark.parametrize("devices", [1, 8])
+def test_every_computing_op_of_the_step_lands_in_a_named_phase(no_persistent_cache, devices):
+    """Each op the step's code makes (its ``op_name`` inside the step's call)
+    is named by a phase, at one GPU and on an eight-GPU fleet."""
+    jobs, policy, tables = _batch()
+    text = _chunk_text(jobs, policy, tables, devices)
+    phases = op_phases(text)
+    unnamed = []
+    for line in text.splitlines():
+        m = re.match(r"^\s+(?:ROOT )?%?(\S+) = ", line)
+        name = re.search(r'op_name="([^"]*)"', line)
+        if (m and name and "closed_call" in name.group(1) and m.group(1) in phases
+                and not phases[m.group(1)] and not _STRUCTURAL.search(line)):
+            unnamed.append(m.group(1))
+    assert unnamed == []
+    assert set(phases.values()) == (ONE_GPU_PHASES if devices == 1 else set(STEP_PHASES)) | {""}
 
 
 def test_chunks_are_remembered_only_while_profiling(monkeypatch):
@@ -186,4 +217,43 @@ ENTRY %main.9 (x: f32[4]) -> f32[4] {
     assert op_phases(text) == {
         "t": "", "gte": "", "fusion.7": "advance", "gather.2": "edf_rank",
         "reduce.3": "policy", "tuple": "", "x": "", "while.1": "",
+    }
+
+
+def test_op_phases_names_a_fused_root_without_metadata_by_its_latest_phase():
+    """The compiler rebuilds some scatters and multi-output fusions without
+    metadata on their root (the fleet chunk's merged write-back on a v5e)."""
+    scope = 'metadata={op_name="jit(run_chunk)/while/body/closed_call/vmap(%s)/scatter"}'
+    text = f"""HloModule jit_run_chunk
+
+%fused_computation.1 (p: f32[4], i: s32[4]) -> f32[4] {{
+  %p = f32[4]{{0}} parameter(0)
+  %i = s32[4]{{0}} parameter(1)
+  %tr = f32[4]{{0}} transpose(%p), dimensions={{0}}, {scope % "writeback"}
+  ROOT %scatter.8 = f32[4]{{0}} scatter(%p, %i, %tr), to_apply=%region_0
+}}
+
+%fused_computation.2 (p: s32[4]) -> (s32[4], s32[4]) {{
+  %p.1 = s32[4]{{0}} parameter(0)
+  %a = s32[4]{{0}} add(%p.1, %p.1), {scope % "repartition"}
+  %b = s32[4]{{0}} add(%a, %p.1), {scope % "edf_rank"}
+  ROOT %tuple.2 = (s32[4]{{0}}, s32[4]{{0}}) tuple(%a, %b)
+}}
+
+%fused_computation.3 (p: s32[4]) -> s32[4] {{
+  %p.2 = s32[4]{{0}} parameter(0)
+  ROOT %c = s32[4]{{0}} copy(%p.2)
+}}
+
+ENTRY %main.9 (x: f32[4], j: s32[4]) -> f32[4] {{
+  %x = f32[4]{{0}} parameter(0)
+  %j = s32[4]{{0}} parameter(1)
+  %fusion.237 = f32[4]{{0}} fusion(%x, %j), kind=kCustom, calls=%fused_computation.1
+  %multiply_reduce_fusion.11 = (s32[4]{{0}}, s32[4]{{0}}) fusion(%j), kind=kLoop, calls=%fused_computation.2
+  ROOT %fusion.9 = s32[4]{{0}} fusion(%j), kind=kLoop, calls=%fused_computation.3
+}}
+"""
+    assert op_phases(text) == {
+        "x": "", "j": "", "fusion.237": "writeback", "multiply_reduce_fusion.11": "edf_rank",
+        "fusion.9": "",
     }

@@ -463,5 +463,11 @@ def test_cellspec_validates_field_combinations():
         CellSpec(
             experiment="t", group="g", scheduler="EDF-SS", seed=1,
             scenario="weekend-flat", fleet_profiles=["a100-250w"],
-            dispatcher="round-robin", backend="batched",
+            dispatcher="energy-greedy", backend="batched",
         ).to_cell()
+    batched_fleet = CellSpec(  # a homogeneous round-robin fleet runs batched
+        experiment="t", group="g", scheduler="EDF-SS", seed=1,
+        scenario="weekend-flat", fleet_profiles=["a100-250w"],
+        dispatcher="round-robin", backend="batched",
+    ).to_cell()
+    assert batched_fleet["backend"] == "batched" and "fleet" in batched_fleet

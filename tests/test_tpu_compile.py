@@ -1,10 +1,12 @@
 """The device path compiles for a described TPU v5e (no chip attached).
 
 Ahead-of-time compiles of the two jitted programs at the sizes the chip
-runs: the simulator's scan chunk for a 512-seed load-12 sweep, the fused
+runs: the simulator's scan chunk for a 512-seed load-12 sweep and for the
+eight-GPU fleet cell, the fused
 training round at the baseline trainer's batch on one chip, and the same
 round with its rollouts sharded over a 2x2 slice.  Each must fit one chip's
-16 GiB.  The TPU compiler refuses here what it would refuse on the chip,
+16 GiB, and every fusion of a scan chunk that runs step code must map to a
+step phase, as the chip benchmark's per-phase readers need.  The TPU compiler refuses here what it would refuse on the chip,
 at no chip time; nothing runs, so this says nothing about results or speed.
 
 The topology is described inside a module fixture, never at import: only
@@ -13,6 +15,7 @@ import every test file.
 """
 
 import os
+import re
 
 import numpy as np
 import pytest
@@ -21,7 +24,13 @@ import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec, SingleDeviceSharding
 
 from repro.core.batched import build_tables, compile_policy
-from repro.core.batched.backend import RolloutState, _chunk_fn, device_constants
+from repro.core.batched.backend import (
+    STEP_PHASES,
+    RolloutState,
+    _chunk_fn,
+    device_constants,
+    op_phases,
+)
 from repro.core.rl.batched_train import BatchedTrainConfig, _make_round_fn
 from repro.core.rl.dqn import DQNConfig, DQNLearner
 from repro.core.rl.env import FEATURE_DIM, RewardWeights
@@ -30,6 +39,7 @@ from repro.core.simulator import DayNightPolicy
 CHIP_BYTES = 16 * 2**30
 SWEEP_B, SWEEP_J = 512, 5760  # 512 seeds of a load-12 paper-diurnal day
 TRAIN_B, TRAIN_J = 64, 736  # the baseline trainer's batch and job axis
+FLEET_B, FLEET_J, FLEET_D = 32, 4096, 8  # the eight-GPU fleet cell's batch
 
 
 @pytest.fixture(scope="module")
@@ -67,6 +77,22 @@ def _fits_one_chip(compiled):
     return total
 
 
+def _unnamed_phased_fusions(text):
+    """Top-level fusions that :func:`op_phases` leaves without a phase
+    while the code fused into them names one."""
+    phases, phased, comp = op_phases(text), set(), None
+    for line in text.splitlines():
+        m = re.match(r"^(?:ENTRY )?%?(\S+) \(.*\{$", line)
+        if m:
+            comp = m.group(1)
+            continue
+        name = re.search(r'op_name="([^"]*)"', line)
+        if comp and name and set(re.split(r"[/()]", name.group(1))) & set(STEP_PHASES):
+            phased.add(comp)
+    fusions = re.findall(r"^\s+(?:ROOT )?%?(\S+) = .* fusion\(.*\bcalls=%?([^\s,]+)", text, re.M)
+    return [op for op, callee in fusions if callee in phased and op in phases and not phases[op]]
+
+
 def _rollout_state(B, J, S, K, sharding):
     f32, i32 = jnp.float32, jnp.int32
     shapes = RolloutState(
@@ -77,8 +103,9 @@ def _rollout_state(B, J, S, K, sharding):
         busy_slot_minutes=((B,), f32), preemptions=((B,), i32),
         repartitions=((B,), i32), util_hist=((B, K), f32),
     )
-    return RolloutState(
-        *(jax.ShapeDtypeStruct(s, d, sharding=sharding) for s, d in shapes)
+    return RolloutState(  # one GPU: no device axis, no ``device``
+        *(None if sd is None else jax.ShapeDtypeStruct(*sd, sharding=sharding)
+          for sd in shapes)
     )
 
 
@@ -120,6 +147,38 @@ def test_scan_chunk_compiles_for_one_v5e(topo, no_persistent_cache):
         consts["old_to_new"], consts["watts"],
     ).compile()
     _fits_one_chip(compiled)
+    assert _unnamed_phased_fusions(compiled.as_text()) == []
+
+
+def test_fleet_chunk_compiles_for_one_v5e(topo, no_persistent_cache):
+    """The eight-GPU fleet's chunk at the benchmark's size: 32 server-days
+    of J=4096 on a device axis of 8 (benchmarks/chip, fleet8-paper-load)."""
+    one = SingleDeviceSharding(topo.devices[0])
+    tables = build_tables()
+    S, K = tables.max_slots, tables.max_slots + 1
+    B, J, D = FLEET_B, FLEET_J, FLEET_D
+    policy = compile_policy(DayNightPolicy(), tables, batch=1)
+    run_chunk = _chunk_fn(
+        policy.kind, 0.5, 512, float(tables.penalty_min),
+        float(policy.day_start), float(policy.day_end), D, "least-loaded",
+    )
+    f32, i32 = jnp.float32, jnp.int32
+    lanes = {"slice_job": ((B, D, S), i32), "cfg": ((B, D), i32), "pending": ((B, D), i32),
+             "stall_left": ((B, D), f32), "device": ((B, J), i32)}
+    state = _rollout_state(B, J, S, K, one)._replace(**{
+        k: jax.ShapeDtypeStruct(s, d, sharding=one) for k, (s, d) in lanes.items()})
+    consts = _like(device_constants(tables, "partial"), one)
+    compiled = run_chunk.lower(
+        state, *_jobs(B, J, K, one),
+        jax.ShapeDtypeStruct((B,), i32, sharding=one),  # primary
+        jax.ShapeDtypeStruct((B,), i32, sharding=one),  # secondary
+        jax.ShapeDtypeStruct((), jnp.float32, sharding=one),  # t0
+        consts["slice_slots"], consts["slice_rank"], consts["num_slices"],
+        consts["old_to_new"], consts["watts"],
+        jax.ShapeDtypeStruct((B, J), i32, sharding=one),  # by_arrival
+    ).compile()
+    _fits_one_chip(compiled)
+    assert _unnamed_phased_fusions(compiled.as_text()) == []
 
 
 def _round_args(B, J, rollout_sharding, other_sharding):
