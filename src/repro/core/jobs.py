@@ -82,12 +82,21 @@ class Elasticity:
 LINEAR = Elasticity(ElasticityClass.LINEAR, "linear", lambda k: k)
 
 
-def capped(cap: int) -> Elasticity:
-    if cap not in (2, 3, 4):
-        raise ValueError(f"paper caps jobs at 2g/3g/4g, got {cap}")
+def _capped_curve(cap: int) -> Elasticity:
     return Elasticity(
         ElasticityClass.CAPPED, f"capped@{cap}g", lambda k, c=cap: min(k, float(c)), cap=cap
     )
+
+
+# lint: waive[VG001] one shared object per cap, built as before; tests/test_front_end_bit_identity.py pins the rates
+# the paper's three caps, built once and shared by every job that draws one
+_PAPER_CAPS: Dict[int, Elasticity] = {c: _capped_curve(c) for c in (2, 3, 4)}
+
+
+def capped(cap: int) -> Elasticity:
+    if cap not in _PAPER_CAPS:
+        raise ValueError(f"paper caps jobs at 2g/3g/4g, got {cap}")
+    return _PAPER_CAPS[cap]
 
 
 def _exp_curve(a: float) -> Callable[[float], float]:
@@ -137,12 +146,7 @@ def elasticity_from_label(label: str) -> Elasticity:
         if cap >= 1:
             # serving slice classes cap at 1 and 7 too (DESIGN.md §9) —
             # same construction as repro.core.serving.class_elasticity
-            return Elasticity(
-                ElasticityClass.CAPPED,
-                f"capped@{cap}g",
-                lambda k, c=cap: min(k, float(c)),
-                cap=cap,
-            )
+            return _capped_curve(cap)
     raise ValueError(
         f"unknown elasticity label {label!r}; valid: 'linear', 'capped@<n>g' "
         f"(n >= 1), or one of {sorted(SUBLINEAR_CURVES)}"

@@ -39,6 +39,7 @@ import numpy as np
 from repro.core.jobs import Job, JobKind
 from repro.core.workload import (
     DIURNAL_RATE_PER_MIN,
+    DiurnalRate,
     MINUTES_PER_DAY,
     WorkloadSpec,
     arrival_rate,
@@ -130,9 +131,7 @@ def _diurnal_jobs(
     spec = WorkloadSpec(horizon_min=horizon_min)
     rng = np.random.default_rng(seed)
     lam_max = max(DIURNAL_RATE_PER_MIN) * load_scale
-    arrivals = sample_poisson_arrivals(
-        horizon_min, lambda t: arrival_rate(t) * load_scale, lam_max, rng
-    )
+    arrivals = sample_poisson_arrivals(horizon_min, DiurnalRate(load_scale), lam_max, rng)
     return jobs_from_arrivals(spec, arrivals, rng, duration_sampler)
 
 

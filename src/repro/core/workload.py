@@ -28,7 +28,6 @@ import numpy as np
 from repro import obs
 from repro.core.jobs import (
     SUBLINEAR_CURVES,
-    Elasticity,
     Job,
     JobKind,
     LINEAR,
@@ -38,6 +37,7 @@ from repro.core.jobs import (
 __all__ = [
     "WorkloadSpec",
     "DIURNAL_RATE_PER_MIN",
+    "DiurnalRate",
     "arrival_rate",
     "generate_jobs",
     "sample_poisson_arrivals",
@@ -58,13 +58,38 @@ DIURNAL_RATE_PER_MIN: Sequence[float] = (
 )
 
 
-def arrival_rate(t_min: float, pattern: Sequence[float] = DIURNAL_RATE_PER_MIN) -> float:
-    """Interpolated arrival rate (jobs/min) at absolute time ``t_min``."""
+def arrival_rate(
+    t_min: float | np.ndarray, pattern: Sequence[float] = DIURNAL_RATE_PER_MIN
+) -> float | np.ndarray:
+    """Interpolated arrival rate (jobs/min) at absolute time ``t_min``.
+
+    ``t_min`` is one time or an array of times: the same float operations
+    run on either (``//`` and ``%`` on floats are floor and modulo in Python
+    and numpy alike), so an array gives, element by element, what the times
+    give one at a time.
+    """
     hod = (t_min / 60.0) % 24.0
-    lo = int(hod) % 24
-    hi = (lo + 1) % 24
-    frac = hod - int(hod)
-    return pattern[lo] * (1.0 - frac) + pattern[hi] * frac
+    whole = hod // 1.0
+    frac = hod - whole
+    if isinstance(hod, np.ndarray):
+        lo, table = whole.astype(np.intp) % 24, np.asarray(pattern, dtype=np.float64)
+    else:
+        lo, table = int(whole) % 24, pattern
+    return table[lo] * (1.0 - frac) + table[(lo + 1) % 24] * frac
+
+
+@dataclasses.dataclass(frozen=True)
+class DiurnalRate:
+    """The Fig. 5 rate times ``load_scale``, at one time or an array of times.
+
+    :func:`sample_poisson_arrivals` evaluates it over all its candidates at
+    once, and accepts the same ones a per-candidate call would.
+    """
+
+    load_scale: float = 1.0
+
+    def __call__(self, t_min: float | np.ndarray) -> float | np.ndarray:
+        return arrival_rate(t_min) * self.load_scale
 
 
 @dataclasses.dataclass(frozen=True)
@@ -109,57 +134,52 @@ def sample_poisson_arrivals(
     returned arrival times are strictly increasing by construction.  The
     scenario library (:mod:`repro.core.scenarios`) reuses this for rate
     patterns the :class:`WorkloadSpec` cannot express (MMPP bursts, scaled
-    traces); the RNG draw sequence is identical to the original in-spec
-    sampler, so the default diurnal path is bit-stable across the refactor.
+    traces).
+
+    The loop only draws: an ``exponential`` gap, then one ``random()`` for
+    the candidate, until a gap crosses the horizon, so the draw sequence
+    does not depend on which candidates are accepted.  Acceptance,
+    ``u * lam_max <= rate_fn(t)``, is evaluated after the loop: at once
+    for a :class:`DiurnalRate`, else candidate by candidate.
     """
-    t = 0.0
-    out: List[float] = []
     with obs.span("scenario.arrivals") as counts:
-        candidates = 0
+        exponential, uniform = rng.exponential, rng.random
+        gap = 1.0 / lam_max
+        times: List[float] = []
+        draws: List[float] = []
+        t = 0.0
         while True:
-            t += rng.exponential(1.0 / lam_max)
+            t += exponential(gap)
             if t >= horizon_min:
                 break
-            candidates += 1
-            if rng.uniform() * lam_max <= rate_fn(t):
-                out.append(t)
-        counts["candidates"], counts["accepted"] = candidates, len(out)
+            times.append(t)
+            draws.append(uniform())
+        ts = np.array(times, dtype=np.float64)
+        rates = (rate_fn(ts) if isinstance(rate_fn, DiurnalRate)
+                 else np.array([rate_fn(c) for c in times], dtype=np.float64))
+        out = ts[np.array(draws, dtype=np.float64) * lam_max <= rates].tolist()
+        counts["candidates"], counts["accepted"] = len(times), len(out)
     return out
 
 
 def _sample_arrivals(spec: WorkloadSpec, rng: np.random.Generator) -> List[float]:
-    return sample_poisson_arrivals(spec.horizon_min, spec.rate, spec.peak_rate, rng)
+    rate = DiurnalRate() if spec.constant_rate is None else spec.rate
+    return sample_poisson_arrivals(spec.horizon_min, rate, spec.peak_rate, rng)
 
 
-def _sample_elasticity(rng: np.random.Generator) -> Elasticity:
-    u = rng.integers(0, 3)
-    if u == 0:
-        return LINEAR
-    if u == 1:
-        return capped(int(rng.choice([2, 3, 4])))
-    label = list(SUBLINEAR_CURVES)[int(rng.integers(0, len(SUBLINEAR_CURVES)))]
-    return SUBLINEAR_CURVES[label]
+# lint: waive[VG001] the same draws in the same order and the same float ops; tests/test_front_end_bit_identity.py pins every job and the generator's state
+#: every curve a job can draw, shared by all the jobs that draw it, indexed
+#: by the draw: linear, capped at 2g/3g/4g, then the sublinear curves in
+#: ``SUBLINEAR_CURVES`` order
+_CURVES = (LINEAR, capped(2), capped(3), capped(4), *SUBLINEAR_CURVES.values())
+#: each curve's throughput on the fastest (7g) slice, which sets the deadline
+_TP_FASTEST = np.array([e.throughput(7) for e in _CURVES], dtype=np.float64)
 
 
 #: Optional per-job duration override: ``(kind, rng) -> work`` in 1g-minutes.
 #: Used by heavy-tailed scenarios; must perform exactly one bounded draw so
 #: job attributes stay deterministic per seed.
 DurationSampler = Callable[[JobKind, np.random.Generator], float]
-
-
-def _sample_work(
-    spec: WorkloadSpec,
-    kind: JobKind,
-    rng: np.random.Generator,
-    duration_sampler: Optional[DurationSampler] = None,
-) -> float:
-    if duration_sampler is not None:
-        return duration_sampler(kind, rng)
-    if kind is JobKind.INFERENCE:
-        # Exp(lambda=3): duration on a 1g slice, minutes.
-        work = rng.exponential(spec.inference_mean_min)
-        return max(work, 1.0 / 60.0)  # floor at one second
-    return rng.uniform(spec.training_lo_min, spec.training_hi_min)
 
 
 def jobs_from_arrivals(
@@ -170,35 +190,49 @@ def jobs_from_arrivals(
 ) -> List[Job]:
     """Draw per-job attributes (§V-A) for pre-sampled arrival times.
 
-    The RNG call sequence per job — split, duration, elasticity, slack — is
-    exactly the legacy ``generate_jobs`` order, so the default path is
-    bit-identical across the refactor.  ``duration_sampler`` swaps only the
-    duration draw (heavy-tailed scenarios).
+    Per job, in this order: split, duration, elasticity class (and its cap
+    or sublinear curve), slack.  Each draw is the cheapest numpy call that
+    consumes and returns exactly what the legacy call did: ``random()`` for
+    ``uniform()``, ``lo + (hi - lo) * random()`` for ``uniform(lo, hi)``,
+    ``integers(0, 3)`` indexing the caps for ``choice([2, 3, 4])``
+    (docs/BATCHED_SIM.md §2).  ``duration_sampler`` swaps only the duration
+    draw (heavy-tailed scenarios).
     """
-    with obs.span("scenario.jobs", jobs=len(arrivals)):
-        jobs: List[Job] = []
-        for i, t in enumerate(arrivals):
-            is_inf = rng.uniform() < spec.inference_split
-            kind = JobKind.INFERENCE if is_inf else JobKind.TRAINING
-            work = _sample_work(spec, kind, rng, duration_sampler)
-            elast = _sample_elasticity(rng)
-            slack = rng.uniform(spec.slack_lo, spec.slack_hi)
-            dur_fastest = elast.duration(work, 7)
-            deadline = t + slack * dur_fastest
-            jobs.append(
-                Job(
-                    job_id=i,
-                    kind=kind,
-                    arrival=t,
-                    work=work,
-                    deadline=deadline,
-                    elasticity=elast,
-                    speedup_no_mig=spec.linear_no_mig_speedup
-                    if elast is LINEAR
-                    else 1.0,
-                )
-            )
-        return jobs
+    n = len(arrivals)
+    with obs.span("scenario.jobs", jobs=n):
+        random, exponential, integers = rng.random, rng.exponential, rng.integers
+        split, inf_mean = spec.inference_split, spec.inference_mean_min
+        train_lo = spec.training_lo_min
+        train_span = spec.training_hi_min - spec.training_lo_min
+        slack_lo, slack_span = spec.slack_lo, spec.slack_hi - spec.slack_lo
+        inference = [False] * n
+        work = [0.0] * n
+        curve = [0] * n
+        slack = [0.0] * n
+        for i in range(n):
+            is_inf = inference[i] = random() < split
+            if duration_sampler is not None:
+                kind = JobKind.INFERENCE if is_inf else JobKind.TRAINING
+                work[i] = duration_sampler(kind, rng)
+            elif is_inf:
+                # Exp(lambda=3): duration on a 1g slice, minutes, floored at one second
+                work[i] = max(exponential(inf_mean), 1.0 / 60.0)
+            else:
+                work[i] = train_lo + train_span * random()
+            klass = integers(0, 3)
+            curve[i] = 0 if klass == 0 else (
+                1 + integers(0, 3) if klass == 1 else 4 + integers(0, len(SUBLINEAR_CURVES)))
+            slack[i] = slack_lo + slack_span * random()
+        fastest = np.array(work, dtype=np.float64) / _TP_FASTEST[curve]
+        deadline = np.asarray(arrivals, dtype=np.float64) + np.array(slack) * fastest
+        no_mig = spec.linear_no_mig_speedup
+        inf_kind, train_kind = JobKind.INFERENCE, JobKind.TRAINING
+        return [
+            Job(i, inf_kind if is_inf else train_kind, t, w, d, _CURVES[c],
+                no_mig if c == 0 else 1.0)
+            for i, (t, is_inf, w, c, d) in enumerate(
+                zip(arrivals, inference, work, curve, deadline.tolist()))
+        ]
 
 
 def generate_jobs(
