@@ -48,8 +48,8 @@ def cluster_benchmark(scale: float = 1.0) -> List[Dict]:
     """Cluster-day benchmark: paper's policies on the TPU pod (DESIGN.md §2)."""
     from benchmarks.common import summarize
     from repro.core.metrics import et_table
-    from repro.core.simulator import DayNightPolicy, StaticPolicy
-    from repro.launch.cluster_sim import queue_heuristic_policy, run_days
+    from repro.core.simulator import DayNightPolicy, StaticPolicy, queue_heuristic_policy
+    from repro.launch.cluster_sim import run_days
     from repro.distributed.fault_tolerance import FailureModel
 
     iters = max(int(5 * scale), 2)

@@ -7,9 +7,9 @@ assigned architectures, with failure injection.
 import argparse
 
 from repro.core.metrics import et_table
-from repro.core.simulator import DayNightPolicy, StaticPolicy
+from repro.core.simulator import DayNightPolicy, StaticPolicy, queue_heuristic_policy
 from repro.distributed.fault_tolerance import FailureModel
-from repro.launch.cluster_sim import queue_heuristic_policy, run_days
+from repro.launch.cluster_sim import run_days
 
 
 def main() -> None:

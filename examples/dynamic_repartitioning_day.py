@@ -16,8 +16,12 @@ from repro.core.metrics import et_table
 from repro.core.rl import evaluate_policy, greedy_policy, train_dqn
 from repro.core.rl.dqn import DQNConfig
 from repro.core.rl.env import FEATURE_DIM
-from repro.core.simulator import DayNightPolicy, NoMIGPolicy, StaticPolicy
-from repro.launch.cluster_sim import queue_heuristic_policy
+from repro.core.simulator import (
+    DayNightPolicy,
+    NoMIGPolicy,
+    StaticPolicy,
+    queue_heuristic_policy,
+)
 
 
 def main() -> None:

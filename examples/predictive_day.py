@@ -19,10 +19,15 @@ import argparse
 from repro.core.metrics import et_table
 from repro.core.scenarios import generate_scenario
 from repro.core.schedulers import make_scheduler
-from repro.core.simulator import DayNightPolicy, MIGSimulator, NoMIGPolicy, StaticPolicy
+from repro.core.simulator import (
+    DayNightPolicy,
+    MIGSimulator,
+    NoMIGPolicy,
+    StaticPolicy,
+    queue_heuristic_policy,
+)
 from repro.core.workload import arrival_rate
 from repro.forecast import ArrivalForecaster, ForecastPolicy, fit_scenario_forecaster
-from repro.launch.cluster_sim import queue_heuristic_policy
 
 
 def main() -> None:

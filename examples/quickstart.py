@@ -16,8 +16,8 @@ from repro.core import (
     et_table,
     generate_jobs,
     make_scheduler,
+    queue_heuristic_policy,
 )
-from repro.launch.cluster_sim import queue_heuristic_policy
 
 
 def main() -> None:

@@ -21,8 +21,7 @@ import argparse
 from repro.core.engine import SimulationEngine
 from repro.core.scenarios import generate_scenario
 from repro.core.schedulers import make_scheduler
-from repro.core.simulator import DayNightPolicy, MIGSimulator
-from repro.launch.cluster_sim import queue_heuristic_policy
+from repro.core.simulator import DayNightPolicy, MIGSimulator, queue_heuristic_policy
 
 
 def make_policy(name: str):

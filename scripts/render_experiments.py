@@ -251,12 +251,8 @@ def _note(row) -> str:
 def perf_md() -> str:
     return (
         "## Perf\n\n"
-        "Kernel and end-to-end performance entry points (numbers live in\n"
+        "End-to-end performance entry points (numbers live in\n"
         "artifacts and the nightly trajectory, not in this file):\n\n"
-        "* `python -m benchmarks.kernels_bench` — Pallas kernels vs reference\n"
-        "  einsum paths (flash attention, Mamba scan, mLSTM, MoE grouped\n"
-        "  matmul); collective overlap notes live in\n"
-        "  `repro/models/transformer.py`.\n"
         "* `python -m benchmarks.run --scale 4 --workers 8` — the paper-table\n"
         "  battery through the sweep engine (the reference EXPERIMENTS\n"
         "  battery used `--scale 4`).\n"

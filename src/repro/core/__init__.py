@@ -41,8 +41,10 @@ from repro.core.simulator import (
     DayNightPolicy,
     MIGSimulator,
     NoMIGPolicy,
+    QueueHeuristicPolicy,
     StaticPolicy,
     REPARTITION_PENALTY_MIN,
+    queue_heuristic_policy,
 )
 
 __all__ = [
@@ -83,6 +85,8 @@ __all__ = [
     "DayNightPolicy",
     "MIGSimulator",
     "NoMIGPolicy",
+    "QueueHeuristicPolicy",
     "StaticPolicy",
     "REPARTITION_PENALTY_MIN",
+    "queue_heuristic_policy",
 ]

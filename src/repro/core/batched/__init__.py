@@ -15,7 +15,6 @@ Public surface:
 * :func:`compile_policy` / :class:`BatchedPolicy` — oracle policies
   compiled to per-rollout target arrays (static/nomig/daynight);
 * :func:`simulate_batch` — run a batch to completion (jax imported here);
-* :class:`BatchedRepartitionEnv` — the vectorized RL environment;
 * :func:`agreement_failures` — the §4 agreement contract with the oracle.
 
 Importing the package is jax-free; jax loads on the first simulated step.
@@ -28,7 +27,6 @@ from repro.core.batched.backend import (
     RolloutState,
     simulate_batch,
 )
-from repro.core.batched.env import BatchedRepartitionEnv
 from repro.core.batched.policies import (
     BatchedPolicy,
     UnsupportedPolicyError,
@@ -44,7 +42,6 @@ __all__ = [
     "PAD_MULTIPLE",
     "BatchedJobs",
     "BatchedPolicy",
-    "BatchedRepartitionEnv",
     "BatchedResult",
     "DeviceTables",
     "RolloutState",

@@ -9,6 +9,7 @@ from repro.core.rl.env import (
 )
 from repro.core.rl.agent import DQNAgent, NStepAccumulator, greedy_policy
 from repro.core.rl.train import train_dqn, evaluate_policy
+from repro.core.rl.batched_env import BatchedRepartitionEnv
 from repro.core.rl.batched_train import (
     BatchedTrainConfig,
     BatchedTrainStats,
@@ -31,4 +32,5 @@ __all__ = [
     "BatchedTrainConfig",
     "BatchedTrainStats",
     "train_dqn_batched",
+    "BatchedRepartitionEnv",
 ]

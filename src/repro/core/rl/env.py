@@ -368,14 +368,14 @@ class RepartitionEnv:
 def make_batched_env(**kwargs):
     """Vectorized counterpart of :class:`RepartitionEnv` (lazy import).
 
-    Returns a :class:`repro.core.batched.BatchedRepartitionEnv` sharing this
-    module's feature/reward contract (same ``M_JOBS``, bin tables and
-    :class:`RewardWeights`), but stepping ``B`` rollouts per call on the
-    batched backend — training scripts collect a whole experience batch per
-    decision interval.  Kwargs are forwarded verbatim; see the batched env
-    for the cadence/scheduler caveats, and keep using :class:`RepartitionEnv`
-    for per-event decisions or non-EDF-FS schedulers.
+    Returns a :class:`repro.core.rl.batched_env.BatchedRepartitionEnv`
+    sharing this module's feature/reward contract (same ``M_JOBS``, bin
+    tables and :class:`RewardWeights`), but stepping ``B`` rollouts per call
+    on the batched backend — training scripts collect a whole experience
+    batch per decision interval.  Kwargs are forwarded verbatim; see the
+    batched env for the cadence/scheduler caveats, and keep using
+    :class:`RepartitionEnv` for per-event decisions or non-EDF-FS schedulers.
     """
-    from repro.core.batched import BatchedRepartitionEnv
+    from repro.core.rl.batched_env import BatchedRepartitionEnv
 
     return BatchedRepartitionEnv(**kwargs)

@@ -32,7 +32,6 @@ from typing import Dict, List, Mapping, Tuple
 
 import numpy as np
 
-from repro.configs import get_config
 from repro.core.jobs import Elasticity, ElasticityClass, Job, JobKind, capped
 from repro.core.workload import (
     DIURNAL_RATE_PER_MIN,
@@ -47,6 +46,7 @@ __all__ = [
     "TenantSpec",
     "SERVING_MIXES",
     "serving_mix",
+    "MODEL_PARAM_COUNTS",
     "model_footprint_gb",
     "model_slice_class",
     "class_elasticity",
@@ -67,10 +67,40 @@ MEMORY_OVERHEAD = 1.25
 _DIURNAL_MEAN = sum(DIURNAL_RATE_PER_MIN) / len(DIURNAL_RATE_PER_MIN)
 
 
+#: parameter count of every architecture in the :mod:`repro.configs`
+#: registry (``get_config(name).param_count()``), held here so the scheduler
+#: never imports the model runtime; tests/test_serving.py pins each entry
+MODEL_PARAM_COUNTS: Dict[str, int] = {
+    "xlstm_350m": 520_706_046,
+    "nemotron_4_340b": 341_025_619_968,
+    "gemma3_12b": 11_765_391_360,
+    "gemma3_1b": 999_811_584,
+    "stablelm_3b": 2_795_274_240,
+    "granite_moe_3b_a800m": 3_298_791_936,
+    "mixtral_8x7b": 46_702_788_608,
+    "whisper_base": 97_942_528,
+    "jamba_v01_52b": 51_569_623_040,
+    "phi3_vision_4_2b": 3_821_076_480,
+}
+
+# external ids whose spelling does not normalize to the table key
+_MODEL_ALIASES = {"jamba-v0.1-52b": "jamba_v01_52b", "phi-3-vision-4.2b": "phi3_vision_4_2b"}
+
+
 def model_footprint_gb(model: str, bytes_per_param: float) -> float:
-    """Serving memory footprint of a deployed model (GB, with overhead)."""
-    params = get_config(model).param_count()
-    return params * bytes_per_param * MEMORY_OVERHEAD / 1e9
+    """Serving memory footprint of a deployed model (GB, with overhead).
+
+    ``model`` is an architecture id or external alias, as
+    ``repro.configs.get_config`` takes it; any other name raises
+    :class:`ModuleNotFoundError`, as there.
+    """
+    key = _MODEL_ALIASES.get(model, model).replace("-", "_").replace(".", "_")
+    if key not in MODEL_PARAM_COUNTS:
+        raise ModuleNotFoundError(
+            f"unknown model {model!r}; registered: {sorted(MODEL_PARAM_COUNTS)}"
+        )
+    # lint: waive[VG001] same counts as repro.configs; tests/test_serving.py pins every entry
+    return MODEL_PARAM_COUNTS[key] * bytes_per_param * MEMORY_OVERHEAD / 1e9
 
 
 def model_slice_class(model: str, bytes_per_param: float) -> Tuple[int, int]:

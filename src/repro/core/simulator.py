@@ -45,6 +45,8 @@ __all__ = [
     "StaticPolicy",
     "NoMIGPolicy",
     "DayNightPolicy",
+    "QueueHeuristicPolicy",
+    "queue_heuristic_policy",
     "CallbackPolicy",
     "MIGSimulator",
     "REPARTITION_PENALTY_MIN",
@@ -145,6 +147,26 @@ class DayNightPolicy:
             if bound > t + _EPS:
                 return bound
         return None  # pragma: no cover
+
+
+# lint: waive[VG001] QueueHeuristicPolicy moved verbatim from launch/cluster_sim.py; tests/test_engine.py and tests/test_repartition.py pin it
+class QueueHeuristicPolicy:
+    """Queue-pressure heuristic (the paper's Fig. 11 intuition distilled)."""
+
+    initial_config = 2
+
+    def decide(self, t: float, sim: "MIGSimulator") -> Optional[int]:
+        snap = sim.snapshot()  # observable state only (engine snapshot API)
+        q = snap.jobs_in_system
+        tgt = 1 if q <= 1 else 2 if q <= 2 else 3 if q <= 3 else 6 if q <= 5 else 9 if q <= 7 else 12
+        return tgt if tgt != snap.config_id else None
+
+    def next_timer(self, t: float) -> Optional[float]:
+        return None
+
+
+def queue_heuristic_policy() -> QueueHeuristicPolicy:
+    return QueueHeuristicPolicy()
 
 
 class CallbackPolicy:

@@ -28,32 +28,14 @@ from repro.core.simulator import (
     MIGSimulator,
     RepartitionPolicy,
     StaticPolicy,
+    queue_heuristic_policy,
 )
 from repro.distributed.fault_tolerance import FailureModel
 
-__all__ = ["FailureAwarePolicy", "queue_heuristic_policy", "run_days", "main"]
+__all__ = ["FailureAwarePolicy", "run_days", "main"]
 
 # pod repartition penalty: rebuild meshes + restore job state from ckpt (min)
 POD_REPARTITION_MIN = 0.5
-
-
-class QueueHeuristicPolicy:
-    """Queue-pressure heuristic (the paper's Fig. 11 intuition distilled)."""
-
-    initial_config = 2
-
-    def decide(self, t, sim):
-        snap = sim.snapshot()  # observable state only (engine snapshot API)
-        q = snap.jobs_in_system
-        tgt = 1 if q <= 1 else 2 if q <= 2 else 3 if q <= 3 else 6 if q <= 5 else 9 if q <= 7 else 12
-        return tgt if tgt != snap.config_id else None
-
-    def next_timer(self, t):
-        return None
-
-
-def queue_heuristic_policy() -> QueueHeuristicPolicy:
-    return QueueHeuristicPolicy()
 
 
 class FailureAwarePolicy:

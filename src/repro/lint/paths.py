@@ -73,6 +73,7 @@ PHYSICS_PATHS = (
     "src/repro/core/metrics.py",
     "src/repro/core/serving.py",
     "src/repro/core/batched",
+    "src/repro/core/rl/batched_env.py",
     "src/repro/fleet",
     "src/repro/forecast",
 )

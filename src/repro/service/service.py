@@ -48,6 +48,7 @@ from repro.core.simulator import (
     DayNightPolicy,
     MIGSimulator,
     NoMIGPolicy,
+    QueueHeuristicPolicy,
     RepartitionPolicy,
     StaticPolicy,
 )
@@ -112,8 +113,6 @@ def make_policy(spec: str, *, repartition_mode: str = "partial") -> RepartitionP
             return DayNightPolicy(day_config=day, night_config=night)
         return DayNightPolicy()
     if name == "heuristic":
-        from repro.launch.cluster_sim import QueueHeuristicPolicy
-
         return QueueHeuristicPolicy()
     if name == "forecast":
         from repro.forecast.policy import ForecastPolicy

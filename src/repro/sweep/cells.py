@@ -41,6 +41,7 @@ from repro.core.simulator import (
     NoMIGPolicy,
     RepartitionPolicy,
     StaticPolicy,
+    queue_heuristic_policy,
 )
 from repro.core.workload import WorkloadSpec, generate_jobs
 
@@ -143,12 +144,6 @@ def _dqn_policy(
     )
 
 
-def _heuristic_policy() -> RepartitionPolicy:
-    from repro.launch.cluster_sim import queue_heuristic_policy
-
-    return queue_heuristic_policy()
-
-
 def _forecast_policy(
     scenario: str = "paper-diurnal",
     train_seeds: int = 8,
@@ -180,7 +175,7 @@ POLICIES: Dict[str, Callable[..., RepartitionPolicy]] = {
     "daynight": lambda day_config=6, night_config=2: DayNightPolicy(
         day_config, night_config
     ),
-    "heuristic": _heuristic_policy,
+    "heuristic": queue_heuristic_policy,
     "dqn": _dqn_policy,
     "forecast": _forecast_policy,
 }

@@ -22,7 +22,6 @@ from hypothesis import strategies as st
 from repro.core.batched import (
     PAD_MULTIPLE,
     BatchedJobs,
-    BatchedRepartitionEnv,
     UnsupportedPolicyError,
     agreement_failures,
     build_tables,
@@ -32,6 +31,7 @@ from repro.core.batched import (
 )
 from repro.core.engine import SimulationEngine
 from repro.core.power import A100_250W
+from repro.core.rl.batched_env import BatchedRepartitionEnv
 from repro.core.scenarios import generate_scenario
 from repro.core.schedulers import make_scheduler
 from repro.core.simulator import (

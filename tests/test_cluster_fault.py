@@ -7,13 +7,13 @@ from repro.cluster.elasticity import arch_elasticity, classify_elasticity, servi
 from repro.cluster.workload import ClusterWorkloadSpec, generate_cluster_jobs
 from repro.core.jobs import ElasticityClass
 from repro.core.metrics import et_table
-from repro.core.simulator import StaticPolicy
+from repro.core.simulator import StaticPolicy, queue_heuristic_policy
 from repro.distributed.fault_tolerance import (
     FailureModel,
     HeartbeatMonitor,
     StragglerDetector,
 )
-from repro.launch.cluster_sim import queue_heuristic_policy, run_days
+from repro.launch.cluster_sim import run_days
 
 
 def test_elasticity_curves_are_valid():

@@ -21,9 +21,9 @@ from repro.core.simulator import (
     MIGSimulator,
     NoMIGPolicy,
     StaticPolicy,
+    queue_heuristic_policy,
 )
 from repro.core.workload import WorkloadSpec, generate_jobs
-from repro.launch.cluster_sim import queue_heuristic_policy
 
 SHORT = WorkloadSpec(horizon_min=180.0, constant_rate=0.4)
 

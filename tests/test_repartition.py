@@ -31,6 +31,7 @@ from repro.core.simulator import (
     MIGSimulator,
     REPARTITION_PENALTY_MIN,
     StaticPolicy,
+    queue_heuristic_policy,
 )
 from repro.core.slices import (
     A30_CONFIGS,
@@ -43,7 +44,6 @@ from repro.core.slices import (
     validate_config_table,
 )
 from repro.core.workload import WorkloadSpec, generate_jobs
-from repro.launch.cluster_sim import queue_heuristic_policy
 
 SCHEDULER_NAMES = ("EDF-FS", "EDF-SS", "EDF-SS-unrestricted", "LLF", "LALF")
 

@@ -14,10 +14,13 @@ import warnings
 
 import pytest
 
+from repro.configs import ALIASES, ARCH_IDS, get_config
 from repro.core.jobs import Job, JobKind
 from repro.core.metrics import TenantSLOStats, merge_tenant_stats, slo_attainment
 from repro.core.scenarios import generate_scenario, scenario_names
 from repro.core.serving import (
+    MEMORY_OVERHEAD,
+    MODEL_PARAM_COUNTS,
     SERVING_MIXES,
     SLICE_CLASSES,
     generate_serving_jobs,
@@ -170,6 +173,23 @@ def test_model_footprint_includes_overhead():
     # overhead multiplier keeps the footprint strictly above raw weights
     raw_gb = 1.0e9 * 1.0 / 1e9
     assert model_footprint_gb("gemma3-1b", 1.0) > raw_gb
+
+
+@pytest.mark.parametrize("name", ARCH_IDS + sorted(ALIASES))
+def test_footprint_matches_the_config_registry(name):
+    # the scheduler keeps its own copy of the counts so it never imports
+    # the model runtime; every id and alias gives the registry's footprint
+    params = get_config(name).param_count()
+    for b in (0.5, 1.0, 2.0):
+        assert model_footprint_gb(name, b) == params * b * MEMORY_OVERHEAD / 1e9
+
+
+def test_param_table_is_the_registry_and_refuses_what_it_refuses():
+    assert MODEL_PARAM_COUNTS == {a: get_config(a).param_count() for a in ARCH_IDS}
+    with pytest.raises(ModuleNotFoundError):
+        get_config("no-such-model")
+    with pytest.raises(ModuleNotFoundError):
+        model_footprint_gb("no-such-model", 1.0)
 
 
 def test_serving_mixes_are_well_formed():

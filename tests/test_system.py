@@ -20,9 +20,9 @@ from repro.core.simulator import (
     MIGSimulator,
     NoMIGPolicy,
     StaticPolicy,
+    queue_heuristic_policy,
 )
 from repro.core.workload import WorkloadSpec, generate_jobs
-from repro.launch.cluster_sim import queue_heuristic_policy
 
 
 def _eval(policy_factory, spec, seeds, scheduler="EDF-SS", mig_enabled=True):
